@@ -62,7 +62,7 @@ def analytic_sweeps(impl: str, rule: str, s: int) -> float:
 
 
 def _pallas_fn(rule, bucket, agg):
-    kw = dict(tile_d=BENCH_TILE_D, interpret=True)
+    kw = dict(tile_d=BENCH_TILE_D)
     if rule in COORD_KERNEL_RULE:
         kernel_rule = COORD_KERNEL_RULE[rule]
         return lambda k, a: ops.robust_agg(
@@ -143,11 +143,11 @@ def giant_n_rows():
             if rule == "krum":
                 def pallas_fn(k, a, n_byz=n_byz):
                     return norm_agg.krum_segments_blocked(
-                        [a], n_byz=n_byz, interpret=True)[0]
+                        [a], n_byz=n_byz)[0]
             else:
                 def pallas_fn(k, a):
                     return norm_agg.rfa_segments_blocked(
-                        [a], iters=GIANT_RFA_T, interpret=True)[0]
+                        [a], iters=GIANT_RFA_T)[0]
             impls = {"jnp": jax.jit(lambda k, a, agg=agg: agg(k, a)),
                      "pallas": pallas_fn}
             for impl, fn in impls.items():

@@ -77,7 +77,7 @@ def run():
             def wire_fn(k, a, comp=comp):
                 wc = wire.pack_candidates(comp, qkeys, {"p": a})
                 return ops.wire_agg(wire.wire_srcs(wc)[0], rule="median",
-                                    tile_d=BENCH_TILE_D, interpret=True)
+                                    tile_d=BENCH_TILE_D)
 
             wc = wire.pack_candidates(comp, qkeys, {"p": x})
             beta = _packed_beta(wc, N, d)

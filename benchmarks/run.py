@@ -26,13 +26,14 @@
             (CI chaos job)
 
 Prints ``name,us_per_call,derived`` CSV. Select a subset with argv, e.g.
-``python -m benchmarks.run fig1 roofline``.
+``python -m benchmarks.run fig1 roofline``. A failed suite prints
+``<name>/SUITE-FAILED`` and the others still run; the exit code is then 1.
 """
 import sys
 import traceback
 
 
-def main() -> None:
+def main() -> int:
     from benchmarks import (bench_ablations, bench_aggregators,
                             bench_compressors, bench_faults, bench_fig1,
                             bench_fig8, bench_obs, bench_roofline,
@@ -54,13 +55,16 @@ def main() -> None:
     }
     chosen = sys.argv[1:] or list(suites)
     print("name,us_per_call,derived")
+    failed = []
     for name in chosen:
         try:
             suites[name]()
         except Exception as e:  # noqa: BLE001 — a broken suite must not
             traceback.print_exc()  # silence the others
             print(f"{name}/SUITE-FAILED,0,{type(e).__name__}: {e}")
+            failed.append(name)
+    return 1 if failed else 0
 
 
 if __name__ == "__main__":
-    main()
+    sys.exit(main())

@@ -21,6 +21,7 @@ pins ``run(spec)`` bit-for-bit against the engine driven the PR-1 way
 from __future__ import annotations
 
 import dataclasses
+import functools
 import json
 import time
 from typing import Any, Callable, Optional
@@ -56,6 +57,23 @@ class Experiment:
     def run(self, **run_kw) -> "RunResult":
         return _run_experiment(self, **run_kw)
 
+    @functools.cached_property
+    def step(self):
+        """The jit-compiled round step the run loop calls."""
+        return jax.jit(self.method.step)
+
+    def start(self):
+        """``(state, k_run)``: the round-0 engine state and the run key of
+        the canonical key schedule (module docstring)."""
+        k_init, k_run = jax.random.split(jax.random.PRNGKey(self.spec.seed))
+        params = self.init_params(k_init)
+        return self.method.init(params, self.anchor(0), k_run), k_run
+
+    def step_args(self, state, it: int, k_run):
+        """The arguments of round ``it``'s step from ``state``."""
+        k_step, k_batch = jax.random.split(jax.random.fold_in(k_run, it + 1))
+        return state, self.minibatch(it, k_batch), self.anchor(it), k_step
+
 
 def build(spec) -> Experiment:
     """Assemble (method, stream, loss_fn, corrupt_fn) for ``spec``."""
@@ -87,7 +105,8 @@ def _attach_all_to_all_mesh(spec, exp: Experiment):
             "with XLA_FLAGS=--xla_force_host_platform_device_count="
             f"{spec.n_workers} (CPU) or on a pod, or use agg_mode='gspmd'")
     mesh = jax.make_mesh((spec.n_workers, n_dev // spec.n_workers),
-                         ("data", "model"))
+                         ("data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
     params_abs = jax.eval_shape(exp.init_params, jax.random.PRNGKey(0))
     if exp.arch_cfg is not None:
         from repro.models import param_specs
@@ -215,7 +234,10 @@ def run(spec, **run_kw) -> RunResult:
       log_every    — record (and with verbose=True, print) every k-th step.
       verbose      — print per-log-step progress lines.
       warmup       — run one throwaway step first (compile) so wall_s is
-                     steady-state; the trajectory is unchanged.
+                     steady-state, and a second from its output when the
+                     step places the state otherwise (all_to_all), so the
+                     rounds after the first do not compile either; the
+                     trajectory is unchanged.
       checkpoint   — path prefix: save the FULL engine state (params +
                      estimator extras + step) via repro.checkpoint, at the
                      end of the run and every ``checkpoint_every`` steps.
@@ -244,6 +266,10 @@ def run(spec, **run_kw) -> RunResult:
     return _run_experiment(build(spec), **run_kw)
 
 
+def _placement(tree) -> list:
+    return [getattr(a, "sharding", None) for a in jax.tree.leaves(tree)]
+
+
 def _run_experiment(exp: Experiment, *, log_every: int = 10,
                     verbose: bool = False, warmup: bool = False,
                     checkpoint: Optional[str] = None,
@@ -260,11 +286,8 @@ def _run_experiment(exp: Experiment, *, log_every: int = 10,
         from repro.obs.sink import FanoutSink, JsonlSink
         own_jsonl = JsonlSink(metrics_jsonl)
         sink = FanoutSink(sink, own_jsonl) if sink is not None else own_jsonl
-    key = jax.random.PRNGKey(spec.seed)
-    k_init, k_run = jax.random.split(key)
-    params = exp.init_params(k_init)
-    n_params = int(tu.tree_size(params))
-    state = exp.method.init(params, exp.anchor(0), k_run)
+    state, k_run = exp.start()
+    n_params = int(tu.tree_size(state["params"]))
     start = 0
     if resume:
         from repro.checkpoint import load_checkpoint
@@ -272,7 +295,7 @@ def _run_experiment(exp: Experiment, *, log_every: int = 10,
         start = int(ck_step or 0)
         if verbose:
             print(f"[run] resumed from {resume}.npz at step {start}")
-    step = jax.jit(exp.method.step)
+    step = exp.step
     step_traced = None
     if spec.trace:
         from repro.obs import detect as obs_detect
@@ -280,11 +303,17 @@ def _run_experiment(exp: Experiment, *, log_every: int = 10,
         step_traced = jax.jit(exp.method.step_traced)
 
     if warmup and spec.steps > 0:
-        k_step, k_batch = jax.random.split(jax.random.fold_in(k_run, 1))
-        wargs = (state, exp.minibatch(0, k_batch), exp.anchor(0), k_step)
+        wargs = exp.step_args(state, 0, k_run)
         thrown, _ = step(*wargs)
         if step_traced is not None:      # compile the telemetry twin too,
             thrown, _ = step_traced(*wargs)   # so log steps never compile
+        if _placement(thrown) != _placement(state):
+            # the step hands its state back placed otherwise (all_to_all's
+            # mesh), and later rounds compile again for that placement
+            wargs = exp.step_args(thrown, 0, k_run)
+            thrown, _ = step(*wargs)
+            if step_traced is not None:
+                thrown, _ = step_traced(*wargs)
         jax.block_until_ready(thrown["g"])
         del thrown, wargs
 
@@ -302,7 +331,6 @@ def _run_experiment(exp: Experiment, *, log_every: int = 10,
     pending_ck = []          # device arrays; synced only on log steps so the
     t0 = time.time()         # loop keeps JAX's async dispatch pipelined
     for it in range(start, spec.steps):
-        k_step, k_batch = jax.random.split(jax.random.fold_in(k_run, it + 1))
         last = it == spec.steps - 1
         do_log = it % max(log_every, 1) == 0 or last
         do_cb = callback is not None and (
@@ -313,8 +341,7 @@ def _run_experiment(exp: Experiment, *, log_every: int = 10,
         # path stays the untraced jaxpr
         fn = step_traced if (step_traced is not None
                              and (do_log or do_cb)) else step
-        state, metrics = fn(state, exp.minibatch(it, k_batch),
-                            exp.anchor(it), k_step)
+        state, metrics = fn(*exp.step_args(state, it, k_run))
         rt = metrics.pop("trace", None) if spec.trace else None
         pending_ck.append(metrics.get("c_k"))
         if do_log or do_cb:

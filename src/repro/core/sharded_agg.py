@@ -52,17 +52,6 @@ from repro.core.aggregators import (COORD_KERNEL_RULE, _bucketize_perm,
                                     coord_median, coord_trimmed_mean)
 
 
-def _shard_map(body, mesh, in_specs, out_specs):
-    """jax.shard_map (new API, check_vma) with a fallback to
-    jax.experimental.shard_map (check_rep) on older jax."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(body, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    from jax.experimental.shard_map import shard_map as _sm
-    return _sm(body, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=False)
-
-
 # route the per-device coordinate rule through the Pallas kernel
 # (kernels/robust_agg.py): fused bucket-mean + sort in VMEM, one HBM sweep.
 # None = auto: default-ON where the kernel compiles (TPU), off on CPU/GPU
@@ -151,7 +140,9 @@ def tree_aggregate_all_to_all(cfg, key, sent):
             g = lax.all_gather(a, w_axes, axis=0, tiled=True)
             return g[:dl].reshape(x.shape[1:]).astype(x.dtype)
 
-        return _shard_map(body, mesh, (in_spec, P()), out_spec)(leaf, key)
+        return jax.shard_map(body, mesh=mesh, in_specs=(in_spec, P()),
+                             out_specs=out_spec,
+                             check_vma=False)(leaf, key)
 
     return jax.tree.map(agg_leaf, sent, specs)
 

@@ -4,7 +4,8 @@
 jit-signature groups (exec/batching.py), runs batchable groups as single
 vmapped trajectories in-process, shards the un-batchable remainder across
 a bounded subprocess pool (per-worker ``CUDA_VISIBLE_DEVICES`` /
-``JAX_PLATFORMS`` pinning, per-cell timeout, failure isolation — one
+``JAX_PLATFORMS`` pinning, one process per TPU, per-cell timeout, failure
+isolation — one
 diverging attack cell records ``failed`` in the ledger and the grid keeps
 going), journals every cell in the crash-safe ledger (exec/ledger.py), and
 writes one artifact JSON per cell (``RunResult.to_dict()``, the same
@@ -141,6 +142,11 @@ class WorkerPool:
     chaos: cells selected by the plan's crash/hang specs get ``--fault``
     on their FIRST attempt only, so with retries enabled the sweep
     completes with artifacts byte-identical to a fault-free run.
+
+    A TPU belongs to one process at a time, and the sweep's own process
+    holds it once it has touched JAX there. ``check_chip_owner`` refuses a
+    pool whose workers would need the chip then; ``jax_platform="cpu"``
+    pins them off it.
     """
     max_workers: int = 2
     timeout_s: Optional[float] = None
@@ -153,6 +159,28 @@ class WorkerPool:
     fault_plan: Optional[object] = None     # faults.FaultPlan or None
     hang_timeout_s: float = 60.0            # cap for injected hangs when
                                             # timeout_s is None
+
+    def check_chip_owner(self) -> None:
+        """Raise unless every worker can get the device it will ask for:
+        workers not pinned to the CPU would need the TPU, which this
+        process already holds (or, several at once, share among
+        themselves)."""
+        import jax
+        child = self.cell_env(None).get("JAX_PLATFORMS", "").lower()
+        if child == "cpu":
+            return
+        if jax.default_backend() == "tpu":
+            raise RuntimeError(
+                "WorkerPool: this process holds the TPU, so worker "
+                "subprocesses that need it would fail or hang (one process "
+                "per chip). Run the cells in-process (no pool / "
+                "--workers 0) or pin the workers to the CPU "
+                "(jax_platform='cpu' / --platform cpu)")
+        if "tpu" in child and self.max_workers > 1:
+            raise RuntimeError(
+                f"WorkerPool: {self.max_workers} workers pinned to "
+                f"JAX_PLATFORMS={child!r} would contend for one chip; use "
+                "max_workers=1 or run the cells in-process")
 
     def cell_env(self, slot) -> dict:
         env = dict(os.environ)
@@ -322,6 +350,8 @@ def run_cells(cells: Sequence[Tuple[str, object]], *,
     if ledger_path:
         ledger = Ledger(ledger_path)
 
+    if pool is not None:
+        pool.check_chip_owner()
     # subprocess workers hand results back as artifact files; without an
     # out_dir they land in a scratch dir so a pool still works (pinning,
     # timeout, isolation) when the caller only wants in-memory results

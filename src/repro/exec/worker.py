@@ -50,6 +50,8 @@ def main(argv=None) -> int:
             time.sleep(3600)
 
     from repro.api import RunSpec, run
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     with open(args.spec) as f:
         spec = RunSpec.from_json(f.read())
     result = run(spec, **json.loads(args.run_kw))

@@ -58,7 +58,11 @@ DEFAULT_TILE_D = 2048     # (64 workers x 2048 lanes x 4B = 512 KiB in VMEM)
 # imports nothing from repro — no cycle).
 from repro.core.aggregators import MAX_FUSED_WORKERS  # noqa: E402
 
-DEFAULT_TILE_N = 64       # worker tile of the blocked kernels
+# worker tile of the blocked kernels. It is also the LANE width of the
+# blocked Gram's (tile_n, tile_n) output blocks, so a multi-block Gram needs
+# a multiple of 128 on the chip; a stack of at most tile_n rows is one
+# full-array block, legal at any height.
+DEFAULT_TILE_N = 128
 
 
 # ---------------------------------------------------------------------------
@@ -184,8 +188,39 @@ def _prologue(env, attack_fn, wire=None):
         # non-finite worker row cannot reach any accumulator.
         x = jnp.where(env["valid"][...] > 0.0, x, 0.0)
     if "w_mat" in env:
-        x = jnp.dot(env["w_mat"][...], x, preferred_element_type=jnp.float32)
+        x = jnp.dot(env["w_mat"][...], x, precision=jax.lax.Precision.HIGHEST,
+                    preferred_element_type=jnp.float32)
     return x
+
+
+def _split_bf16(v):
+    """f32 ``v`` as hi + mid + lo, three bf16 terms that hold all of its
+    24-bit significand."""
+    hi = v.astype(jnp.bfloat16)
+    r = v - hi.astype(jnp.float32)
+    mid = r.astype(jnp.bfloat16)
+    return hi, mid, (r - mid.astype(jnp.float32)).astype(jnp.bfloat16)
+
+
+def _gram_dot(a, b, *, mxu: bool):
+    """``a @ b.T`` of f32 blocks, f32-accurate. ``mxu`` (compiled for the
+    TPU, which multiplies bf16): the six bf16 products of the split operands
+    above 2^-24, smallest first, each exact in its f32 accumulator;
+    ``Precision.HIGHEST`` in Mosaic keeps fewer and is off by about 2.5e-5
+    relative on a 2^18-wide Gram on a v5e. Otherwise (interpret mode) the
+    host's f32 dot, which is f32-accurate itself."""
+    if not mxu:
+        return jnp.dot(a, b.T, precision=jax.lax.Precision.HIGHEST,
+                       preferred_element_type=jnp.float32)
+    ah, am, al = _split_bf16(a)
+    bh, bm, bl = _split_bf16(b)
+
+    def nt(x, y):
+        return jax.lax.dot_general(x, y, (((1,), (1,)), ((), ())),
+                                   preferred_element_type=jnp.float32)
+
+    return (((nt(al, bh) + nt(ah, bl)) + nt(am, bm))
+            + (nt(am, bh) + nt(ah, bm))) + nt(ah, bh)
 
 
 # ---------------------------------------------------------------------------
@@ -201,6 +236,7 @@ def pair_gram(x, w_mat=None, mask=None, good_mean=None, good_std=None,
     stack; m = nb when ``w_mat`` is given else n. Krum's pairwise squared
     distances are d²[i,j] = G[i,i] + G[j,j] - 2 G[i,j]."""
     n, d = src_dims(x)
+    interpret = resolve_interpret(interpret)
     m = w_mat.shape[0] if w_mat is not None else n
     vals, specs, names, grid, dp, wire = _assemble(x, w_mat, mask, good_mean,
                                                    good_std, tile_d,
@@ -215,7 +251,7 @@ def pair_gram(x, w_mat=None, mask=None, good_mean=None, good_std=None,
         def _():
             o_ref[...] = jnp.zeros_like(o_ref)
 
-        o_ref[...] += jnp.dot(xb, xb.T, preferred_element_type=jnp.float32)
+        o_ref[...] += _gram_dot(xb, xb, mxu=not interpret)
 
     return pl.pallas_call(
         kernel,
@@ -223,7 +259,7 @@ def pair_gram(x, w_mat=None, mask=None, good_mean=None, good_std=None,
         in_specs=specs,
         out_specs=pl.BlockSpec((m, m), lambda i: (0, 0)),
         out_shape=jax.ShapeDtypeStruct((m, m), jnp.float32),
-        interpret=resolve_interpret(interpret),
+        interpret=interpret,
     )(*vals)
 
 
@@ -463,6 +499,7 @@ def pair_gram_blocked(x, *, tile_n: int = DEFAULT_TILE_N,
     accumulates its d-sweep in VMEM. Peak VMEM is 2·(tile_n, tile_d) input
     blocks + one (tile_n, tile_n) accumulator, independent of m and d."""
     m, d = x.shape
+    interpret = resolve_interpret(interpret)
     tile = _tile_for(d, tile_d)
     dp = -(-d // tile) * tile
     tn = _tile_n_for(m, tile_n)
@@ -474,8 +511,7 @@ def pair_gram_blocked(x, *, tile_n: int = DEFAULT_TILE_N,
         def _():
             o_ref[...] = jnp.zeros_like(o_ref)
 
-        o_ref[...] += jnp.dot(a_ref[...], b_ref[...].T,
-                              preferred_element_type=jnp.float32)
+        o_ref[...] += _gram_dot(a_ref[...], b_ref[...], mxu=not interpret)
 
     g = pl.pallas_call(
         kernel,
@@ -484,7 +520,7 @@ def pair_gram_blocked(x, *, tile_n: int = DEFAULT_TILE_N,
                   pl.BlockSpec((tn, tile), lambda i, j, k: (j, k))],
         out_specs=pl.BlockSpec((tn, tn), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((mp, mp), jnp.float32),
-        interpret=resolve_interpret(interpret),
+        interpret=interpret,
     )(xp, xp)
     return g[:m, :m]
 

@@ -23,9 +23,9 @@ Formats (payloads are worker-stacked (n, ...) on the kernel side):
   sparse  — vals (n, k) leaf-dtype + idx (n, k) int32 ascending (randk keeps
             the d/k unbiasedness scaling in vals; topk values ride raw).
             In-kernel reconstruction is a windowed one-hot matmul: CSR-style
-            row pointers (``starts``, built once per launch by searchsorted)
-            bound each (worker, tile) segment, and fixed-size value chunks
-            scatter into the tile on the MXU.
+            row pointers (built once per launch by searchsorted, read from
+            SMEM) bound each (worker, tile) segment, and lane-aligned value
+            chunks DMA'd from HBM scatter into the tile on the MXU.
   int8    — levels (n, ceil(d/B)·B) int8 + per-block norms (n, ceil(d/B))
             f32, B = compressors.INT8_BLOCK; dequantized blockwise in VMEM.
   sign    — signs (n, d) int8 in {-1, 0, 1} + scale (n, 1) f32.
@@ -43,6 +43,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.backend import resolve_interpret
 from repro.core.compressors import (INT8_BLOCK, INT8_LEVELS, _int8_decode,
@@ -54,47 +55,51 @@ DEFAULT_TILE_D = 2048
 WIRE_FORMATS = ("sparse", "int8", "sign", "bf16", "dense32")
 
 # sparse reconstruction: value chunk width for the windowed one-hot matmul.
-# Lane-aligned; (CHUNK, tile) one-hot = 128·2048·4B = 1 MiB VMEM at the
-# default tile.
+# Lane-aligned; (tile, CHUNK) one-hot = 2048·128·4B = 1 MiB VMEM at the
+# default tile. The payload rides in HBM as (n, kp / CHUNK, CHUNK) rows and
+# is DMA'd in SLAB_ROWS-row slabs: 16 rows align to the sublane tiling of
+# 32- and 16-bit payloads alike.
 SCATTER_CHUNK = 128
+SLAB_ROWS = 16
 
 
-def _quant_kernel(x_ref, u_ref, o_ref, *, levels, block):
-    x = x_ref[...].astype(jnp.float32)            # (TILE_D,)
-    u = u_ref[...].astype(jnp.float32)
-    xb = x.reshape(-1, block)
-    ub = u.reshape(-1, block)
+def _quant_kernel(x_ref, u_ref, o_ref, *, levels):
+    xb = x_ref[...].astype(jnp.float32)           # (TILE_D / block, block)
+    ub = u_ref[...].astype(jnp.float32)
     norm = jnp.sqrt(jnp.sum(xb * xb, axis=1, keepdims=True))
     scaled = jnp.where(norm > 0, jnp.abs(xb) / jnp.maximum(norm, 1e-30), 0.0)
     level = jnp.floor(scaled * levels + ub)
-    out = norm * jnp.sign(xb) * level / levels
-    o_ref[...] = out.reshape(x.shape)
+    o_ref[...] = norm * jnp.sign(xb) * level / levels
 
 
 @functools.partial(jax.jit, static_argnames=("levels", "block", "tile_d",
                                              "interpret"))
 def block_quantize(x, u, *, levels: int = 4, block: int = 256,
                    tile_d: int = DEFAULT_TILE_D, interpret=None):
-    """x, u: (d,). Returns dequantized (d,) float32. d padded to tile_d;
-    tile_d must be a multiple of ``block``. ``interpret=None`` resolves per
-    backend (kernels/backend.py)."""
+    """x, u: (d,). Returns dequantized (d,) float32. tile_d must be a
+    multiple of ``block``. The vectors ride as (d / block, block) rows —
+    one quantization block per row, a sublane-aligned number of rows per
+    grid step — so the per-block norm is a lane reduction and no in-kernel
+    reshape exists. Zero padding forms whole zero blocks (norm 0 -> 0).
+    ``interpret=None`` resolves per backend (kernels/backend.py)."""
     assert tile_d % block == 0
     d = x.shape[0]
-    pad = (-d) % tile_d
+    rows = -(-(tile_d // block) // 8) * 8
+    pad = (-d) % (rows * block)
     if pad:
         x = jnp.pad(x, (0, pad))
         u = jnp.pad(u, (0, pad))
     dp = d + pad
+    spec = pl.BlockSpec((rows, block), lambda i: (i, 0))
     out = pl.pallas_call(
-        functools.partial(_quant_kernel, levels=levels, block=block),
-        grid=(dp // tile_d,),
-        in_specs=[pl.BlockSpec((tile_d,), lambda i: (i,)),
-                  pl.BlockSpec((tile_d,), lambda i: (i,))],
-        out_specs=pl.BlockSpec((tile_d,), lambda i: (i,)),
-        out_shape=jax.ShapeDtypeStruct((dp,), jnp.float32),
+        functools.partial(_quant_kernel, levels=levels),
+        grid=(dp // (rows * block),),
+        in_specs=[spec, spec],
+        out_specs=spec,
+        out_shape=jax.ShapeDtypeStruct((dp // block, block), jnp.float32),
         interpret=resolve_interpret(interpret),
-    )(x, u)
-    return out[:d]
+    )(x.reshape(-1, block), u.reshape(-1, block))
+    return out.reshape(-1)[:d]
 
 
 # ---------------------------------------------------------------------------
@@ -153,7 +158,6 @@ class WireMeta:
     n: int
     d: int
     tile: int
-    kp: int = 0          # sparse: padded wire length per worker
     base_rows: int = 0
     cand_dtype: object = jnp.float32
 
@@ -165,45 +169,73 @@ class WireMeta:
 def topk_select(x, k: int, *, tile_d: int = DEFAULT_TILE_D, interpret=None):
     """Indices of the k largest |x| — ``lax.top_k(|x|, k)[1]`` semantics.
 
-    Multi-tile inputs run the selection on-chip: a Pallas pass keeps each
-    tile's top-c candidates (c = min(k, tile), lane-padded) in VMEM and
-    writes only the (T, c) pool; the final exact top-k runs on the tiny
-    pool. Every global top-k element is inside its own tile's top-c, so the
-    pool provably contains the answer. Cross-tile ties of equal |x| may
-    break differently from the dense sort (by pool rank, not global index).
+    Multi-tile inputs with k below a tile run the selection on-chip: a
+    Pallas pass keeps each tile's top-cp candidates (cp = min(k, tile),
+    lane-padded) in VMEM and writes only the (T, cp) pool; the final exact
+    top-k runs on the tiny pool. Every global top-k element is inside its
+    own tile's top-cp, so the pool provably contains the answer. Cross-tile
+    ties of equal |x| may break differently from the dense sort (by pool
+    rank, not global index). When the pool would be the whole tile the
+    pass selects nothing, so the dense top-k runs directly.
+
+    The in-kernel selection is cp rounds of max extraction over the
+    (tile/128, 128) block — Mosaic lowers neither ``top_k`` nor gathers —
+    each round taking the largest remaining |x| at its lowest index, which
+    is ``lax.top_k``'s order.
     """
     xf = x.reshape(-1)
     d = xf.shape[0]
     tile = _lane_tile(d, tile_d)
-    if d <= 2 * tile:
-        return lax.top_k(jnp.abs(xf.astype(jnp.float32)), k)[1]
     cp = min(tile, max(128, -(-min(k, tile) // 128) * 128))
+    if d <= 2 * tile or cp == tile:
+        return lax.top_k(jnp.abs(xf.astype(jnp.float32)), k)[1]
+    pv, pi = topk_pool(xf, cp, tile=tile, interpret=interpret)
+    _, sel = lax.top_k(pv.reshape(-1), k)
+    return jnp.take(pi.reshape(-1), sel)
+
+
+@functools.partial(jax.jit, static_argnames=("cp", "tile", "interpret"))
+def topk_pool(xf, cp: int, *, tile: int, interpret=None):
+    """The on-chip pass of ``topk_select``: each tile's cp largest |x| (in
+    ``lax.top_k`` order) and their global indices, as (T, 1, cp) pools."""
+    d = xf.shape[0]
     dp = -(-d // tile) * tile
     t_count = dp // tile
-    xp = jnp.pad(xf.astype(jnp.float32), (0, dp - d))
+    rows = tile // 128
+    xp = jnp.pad(xf.astype(jnp.float32), (0, dp - d)).reshape(-1, 128)
 
     def kern(x_ref, v_ref, i_ref):
         t = pl.program_id(0)
-        xt = x_ref[...].reshape(-1)
         gidx = (t * tile
-                + lax.broadcasted_iota(jnp.int32, (1, tile), 1).reshape(-1))
-        a = jnp.where(gidx < d, jnp.abs(xt), -1.0)   # pad below any real |x|
-        av, ai = lax.top_k(a, cp)
-        v_ref[...] = av.reshape(1, cp)
-        i_ref[...] = jnp.take(gidx, ai).reshape(1, cp)
+                + 128 * lax.broadcasted_iota(jnp.int32, (rows, 128), 0)
+                + lax.broadcasted_iota(jnp.int32, (rows, 128), 1))
+        a = jnp.where(gidx < d, jnp.abs(x_ref[...]), -1.0)  # pad below |x|
+        slot = lax.broadcasted_iota(jnp.int32, (1, cp), 1)
 
-    pv, pi = pl.pallas_call(
+        def take(j, carry):
+            a, vals, idxs = carry
+            top = jnp.max(a)
+            at = jnp.min(jnp.where(a == top, gidx, dp))
+            vals = jnp.where(slot == j, top, vals)
+            idxs = jnp.where(slot == j, at, idxs)
+            return jnp.where(gidx == at, -jnp.inf, a), vals, idxs
+
+        _, vals, idxs = lax.fori_loop(
+            0, cp, take, (a, jnp.zeros((1, cp), jnp.float32),
+                          jnp.zeros((1, cp), jnp.int32)))
+        v_ref[0] = vals
+        i_ref[0] = idxs
+
+    return pl.pallas_call(
         kern,
         grid=(t_count,),
-        in_specs=[pl.BlockSpec((tile,), lambda i: (i,))],
-        out_specs=(pl.BlockSpec((1, cp), lambda i: (i, 0)),
-                   pl.BlockSpec((1, cp), lambda i: (i, 0))),
-        out_shape=(jax.ShapeDtypeStruct((t_count, cp), jnp.float32),
-                   jax.ShapeDtypeStruct((t_count, cp), jnp.int32)),
+        in_specs=[pl.BlockSpec((rows, 128), lambda i: (i, 0))],
+        out_specs=(pl.BlockSpec((1, 1, cp), lambda i: (i, 0, 0)),
+                   pl.BlockSpec((1, 1, cp), lambda i: (i, 0, 0))),
+        out_shape=(jax.ShapeDtypeStruct((t_count, 1, cp), jnp.float32),
+                   jax.ShapeDtypeStruct((t_count, 1, cp), jnp.int32)),
         interpret=resolve_interpret(interpret),
     )(xp)
-    _, sel = lax.top_k(pv.reshape(-1), k)
-    return jnp.take(pi.reshape(-1), sel)
 
 
 def pack_sparse(key, x, ratio: float, *, topk: bool):
@@ -290,10 +322,10 @@ def wire_inputs(src: WireSrc, tile: int, dp: int):
     """Build (vals, specs, names, meta) for the aggregation kernels.
 
     Dense-ish payloads (int8 / sign / bf16 / base) ride as (n, tile) blocks
-    like x would; the sparse wire rides WHOLE as constant revisited VMEM
-    blocks (vals/idx/starts), with CSR row pointers built here once by
-    searchsorted. Column pads use value 0 (decode-neutral) and index
-    sentinel dp (matches no tile).
+    like x would; the sparse wire's vals/idx stay in HBM for the kernel to
+    window into, with CSR row pointers built here once by searchsorted.
+    Column pads use value 0 (decode-neutral) and index sentinel dp
+    (matches no tile).
     """
     n, d = src.n, src.d
     arr = dict(src.arrays)
@@ -304,27 +336,36 @@ def wire_inputs(src: WireSrc, tile: int, dp: int):
         specs.append(spec)
         names.append(name)
 
-    kp = 0
     if src.fmt == "sparse":
         v, ix = arr["vals"], arr["idx"]
-        kp = max(SCATTER_CHUNK, -(-v.shape[1] // 128) * 128)
-        v = _pad_to(v, kp)
+        slab = SLAB_ROWS * SCATTER_CHUNK
+        kp = -(-v.shape[1] // slab) * slab
+        v = _pad_to(v, kp).reshape(n, -1, SCATTER_CHUNK)
         ix = _pad_to(ix, kp, fill=dp)          # sentinel: outside every tile
         t_count = dp // tile
         bounds = jnp.arange(t_count + 1, dtype=jnp.int32) * tile
         starts = jax.vmap(
             lambda row: jnp.searchsorted(row, bounds).astype(jnp.int32))(ix)
-        sp = -(-(t_count + 1) // 128) * 128
-        starts = _pad_to(starts, sp)
-        add("w_vals", v, pl.BlockSpec((n, kp), lambda i: (0, 0)))
-        add("w_idx", ix, pl.BlockSpec((n, kp), lambda i: (0, 0)))
-        add("w_starts", starts, pl.BlockSpec((n, sp), lambda i: (0, 0)))
+        # vals/idx stay in HBM (a (n, k) stack outgrows VMEM at real d);
+        # the kernel DMAs the windows it needs. Each tile's row pointers
+        # ride as one whole (1, n) SMEM slab of the (T, 1, n) tables.
+        hbm = pl.BlockSpec(memory_space=pl.ANY)
+        ptr = pl.BlockSpec((1, 1, n), lambda i: (i, 0, 0),
+                           memory_space=pltpu.SMEM)
+        add("w_vals", v, hbm)
+        add("w_idx", ix.reshape(n, -1, SCATTER_CHUNK), hbm)
+        add("w_lo", starts[:, :-1].T.reshape(t_count, 1, n), ptr)
+        add("w_hi", starts[:, 1:].T.reshape(t_count, 1, n), ptr)
     elif src.fmt == "int8":
         nb_t = tile // INT8_BLOCK
         lev = _pad_to(arr["lev"], dp)
+        # (T, n, nb_t): each tile's norms are one whole trailing (n, nb_t)
+        # slab, a legal block although nb_t is narrower than a lane tile
         norms = _pad_to(arr["norms"], dp // INT8_BLOCK)
+        norms = norms.reshape(n, dp // tile, nb_t).transpose(1, 0, 2)
         add("w_lev", lev, pl.BlockSpec((n, tile), lambda i: (0, i)))
-        add("w_norms", norms, pl.BlockSpec((n, nb_t), lambda i: (0, i)))
+        add("w_norms", norms,
+            pl.BlockSpec((1, n, nb_t), lambda i: (i, 0, 0)))
     elif src.fmt == "sign":
         add("w_signs", _pad_to(arr["signs"], dp),
             pl.BlockSpec((n, tile), lambda i: (0, i)))
@@ -342,42 +383,57 @@ def wire_inputs(src: WireSrc, tile: int, dp: int):
         add("w_base", _pad_to(src.base, dp),
             pl.BlockSpec((base_rows, tile), lambda i: (0, i)))
 
-    meta = WireMeta(fmt=src.fmt, n=n, d=d, tile=tile, kp=kp,
+    meta = WireMeta(fmt=src.fmt, n=n, d=d, tile=tile,
                     base_rows=base_rows, cand_dtype=src.cand_dtype)
     return vals, specs, names, meta
 
 
 def _recon_sparse_block(env, meta: WireMeta):
     """(n, tile) f32 payload values of the current tile, decoded from the
-    CSR-windowed wire — a chunked one-hot matmul per worker, bounded by the
-    row pointers so total work is O(n·k·tile/d + chunk·tile) per tile."""
-    n, tile, kp = meta.n, meta.tile, meta.kp
-    t = pl.program_id(0)
-    lo = t * tile
+    CSR-windowed wire — a chunked one-hot matmul per worker over the
+    lane-aligned chunks that overlap its [lo, hi) segment, so total work is
+    O(n·k·tile/d + n·chunk·tile) per tile. Each chunk's slab is DMA'd from
+    HBM and the chunk's row picked out by a select-reduce."""
+    n, tile = meta.n, meta.tile
+    ch = SCATTER_CHUNK
+    lo = pl.program_id(0) * tile
     vref, iref = env["w_vals"], env["w_idx"]
-    starts = env["w_starts"][...]
-    cols = lax.broadcasted_iota(jnp.int32, (SCATTER_CHUNK, tile), 1)
-    rows = []
-    for i in range(n):
-        s = starts[i, t]
-        e = starts[i, t + 1]
-        n_chunks = (e - s + SCATTER_CHUNK - 1) // SCATTER_CHUNK
+    seg_lo, seg_hi = env["w_lo"], env["w_hi"]
+    cols = lo + lax.broadcasted_iota(jnp.int32, (tile, ch), 0)
+    lanes = lax.broadcasted_iota(jnp.int32, (1, ch), 1)
+    slab_row = lax.broadcasted_iota(jnp.int32, (SLAB_ROWS, ch), 0)
 
-        def body(c, acc, i=i, s=s, e=e):
-            p0 = s + c * SCATTER_CHUNK
-            w0 = jnp.minimum(p0, kp - SCATTER_CHUNK)   # clamped window start
-            v = vref[pl.ds(i, 1), pl.ds(w0, SCATTER_CHUNK)]
-            ix = iref[pl.ds(i, 1), pl.ds(w0, SCATTER_CHUNK)]
-            pos = w0 + lax.broadcasted_iota(jnp.int32, (1, SCATTER_CHUNK), 1)
-            live = ((pos >= p0) & (pos < e)           # this chunk's segment
-                    & (ix >= lo) & (ix < lo + tile))  # sentinel guard
-            vm = jnp.where(live, v.astype(jnp.float32), 0.0)
-            oh = jnp.where(ix.reshape(-1)[:, None] - lo == cols, 1.0, 0.0)
-            return acc + jnp.dot(vm, oh, preferred_element_type=jnp.float32)
+    def scoped(vbuf, ibuf, out):
+        for i in range(n):
+            s, e = seg_lo[0, 0, i], seg_hi[0, 0, i]
 
-        rows.append(lax.fori_loop(0, n_chunks, body,
-                                  jnp.zeros((1, tile), jnp.float32)))
-    return jnp.concatenate(rows, axis=0)
+            def body(c, acc, i=i, s=s, e=e):
+                r0 = pl.multiple_of(c // SLAB_ROWS * SLAB_ROWS, SLAB_ROWS)
+                pltpu.sync_copy(vref.at[i, pl.ds(r0, SLAB_ROWS), :], vbuf)
+                pltpu.sync_copy(iref.at[i, pl.ds(r0, SLAB_ROWS), :], ibuf)
+                row = slab_row == c - r0
+                v = jnp.sum(jnp.where(row, vbuf[...].astype(jnp.float32),
+                                      0.0), axis=0, keepdims=True)
+                ix = jnp.sum(jnp.where(row, ibuf[...], 0), axis=0,
+                             keepdims=True)
+                pos = c * ch + lanes
+                live = (pos >= s) & (pos < e)        # this row's segment
+                vm = jnp.where(live, v, 0.0)
+                oh = jnp.where(cols == ix, 1.0, 0.0)          # (tile, ch)
+                return acc + lax.dot_general(
+                    vm, oh, (((1,), (1,)), ((), ())),
+                    precision=lax.Precision.HIGHEST,
+                    preferred_element_type=jnp.float32)
+
+            out[i:i + 1, :] = lax.fori_loop(
+                s // ch, (e + ch - 1) // ch, body,
+                jnp.zeros((1, tile), jnp.float32))
+        return out[...]
+
+    return pl.run_scoped(
+        scoped, pltpu.VMEM((SLAB_ROWS, ch), vref.dtype),
+        pltpu.VMEM((SLAB_ROWS, ch), jnp.int32),
+        pltpu.VMEM((n, tile), jnp.float32))
 
 
 def recon_block(env, meta: WireMeta):
@@ -389,11 +445,13 @@ def recon_block(env, meta: WireMeta):
         q = _recon_sparse_block(env, meta)
     elif meta.fmt == "int8":
         lev = env["w_lev"][...].astype(jnp.float32)       # (n, tile)
-        norms = env["w_norms"][...]                        # (n, tile/B)
-        nb = norms.shape[1]
-        scale = jnp.broadcast_to(norms[:, :, None],
-                                 (meta.n, nb, INT8_BLOCK))
-        q = scale.reshape(meta.n, -1) * lev / INT8_LEVELS
+        norms = env["w_norms"][0]                          # (n, tile/B)
+        # expand each block's norm over its B lanes with selects (exact;
+        # a lane-splitting reshape does not lower)
+        blk = lax.broadcasted_iota(jnp.int32, (1, meta.tile), 1) // INT8_BLOCK
+        scale = sum(jnp.where(blk == b, norms[:, b:b + 1], 0.0)
+                    for b in range(norms.shape[1]))
+        q = scale * lev / INT8_LEVELS
     elif meta.fmt == "sign":
         q = env["w_signs"][...].astype(jnp.float32) * env["w_scale"][...]
     elif meta.fmt == "bf16":

@@ -18,8 +18,10 @@ tensor never hits HBM. The legacy contiguous path (pre-permuted rows +
 
 TPU adaptation: the worker axis (n ≤ norm_agg.MAX_FUSED_WORKERS = 64) lives
 in the sublane dimension; TILE_D is lane-aligned (multiple of 128).
-``jnp.sort`` along axis 0 inside the kernel lowers to a fixed-size bitonic
-network over sublanes. Giant-n stacks never reach this kernel: callers
+Mosaic has no ``sort`` lowering, so the rows are ordered by a fixed Batcher
+odd-even merge network of elementwise ``minimum``/``maximum`` over row
+slices (``_sort_rows``) — exact selection, static in n. Giant-n stacks never
+reach this kernel: callers
 (kernels/ops.py, core/sharded_agg.py) bucket-reduce first and run the
 coordinate rule in jnp — DESIGN.md §7.
 """
@@ -36,6 +38,45 @@ from repro.kernels.norm_agg import _assemble, _prologue, src_dims
 
 
 DEFAULT_TILE_D = 2048     # (64 workers x 2048 lanes x 4B = 512 KiB in VMEM)
+
+
+def _merge_sort_pairs(m: int):
+    """Comparators (lo, hi) of Batcher's odd-even merge sort for m rows.
+
+    Built for the next power of two and pruned to hi < m: every comparator
+    puts the min at the lower index, so virtual +inf rows past m would
+    never move and each pruned comparator is a no-op."""
+    p = 1
+    while p < m:
+        p *= 2
+    pairs = []
+    t = 1
+    while t < p:
+        k = t
+        while k >= 1:
+            for j in range(k % t, p - k, 2 * k):
+                for i in range(min(k, p - j - k)):
+                    if (i + j) // (2 * t) == (i + j + k) // (2 * t):
+                        pairs.append((i + j, i + j + k))
+            k //= 2
+        t *= 2
+    return [(a, b) for a, b in pairs if b < m]
+
+
+def _sort_rows(x):
+    """Rows of the (m, tile) block in ascending order per column, as a list
+    of (1, tile) rows: ``jnp.sort(x, axis=0)``, NaN sorting last. min/max
+    would propagate a NaN, so the network orders NaN as +inf and the last
+    (NaN count) rows of each column are set back to NaN afterwards."""
+    m = x.shape[0]
+    rows = [x[i:i + 1, :] for i in range(m)]
+    nans = [r != r for r in rows]
+    n_nan = sum(v.astype(jnp.int32) for v in nans)
+    rows = [jnp.where(v, jnp.inf, r) for v, r in zip(nans, rows)]
+    for a, b in _merge_sort_pairs(m):
+        rows[a], rows[b] = (jnp.minimum(rows[a], rows[b]),
+                            jnp.maximum(rows[a], rows[b]))
+    return [jnp.where(n_nan >= m - i, jnp.nan, r) for i, r in enumerate(rows)]
 
 
 def _coord_rule_block(x, *, bucket_size, rule, trim, n):
@@ -55,14 +96,14 @@ def _coord_rule_block(x, *, bucket_size, rule, trim, n):
     m = x.shape[0]
     if rule == "mean":
         return jnp.mean(x, axis=0)
-    xs = jnp.sort(x, axis=0)
+    xs = _sort_rows(x)
     if rule == "median":
         if m % 2:
-            return xs[m // 2]
-        return 0.5 * (xs[m // 2 - 1] + xs[m // 2])
+            return xs[m // 2][0]
+        return 0.5 * (xs[m // 2 - 1][0] + xs[m // 2][0])
     if rule == "trimmed":
         t = min(trim, (m - 1) // 2)
-        return jnp.mean(xs[t:m - t], axis=0)
+        return sum(xs[t + 1:m - t], xs[t])[0] / (m - 2 * t)
     raise ValueError(rule)
 
 
@@ -71,27 +112,28 @@ def _masked_coord_rule_block(x, bvalid, *, rule, trim):
 
     ``x`` (m, tile) is already sanitized (+ W-bucketed) by ``_prologue``;
     ``bvalid`` (m, 1) marks the rows (buckets) with at least one valid
-    member. Invalid rows re-fill with +inf so the sublane sort pushes them
-    past every real entry, and the selection ranks track the TRACED valid
-    count c — the in-kernel twin of ``aggregators.masked_coord_median`` /
-    ``masked_coord_trimmed_mean``. Rank gathers are iota-compare selects
-    (dynamic sublane indexing doesn't vectorize on the VPU)."""
+    member. Invalid rows re-fill with +inf so the sorting network pushes
+    them past every real entry, and the selection ranks track the TRACED
+    valid count c — the in-kernel twin of ``aggregators.masked_coord_median``
+    / ``masked_coord_trimmed_mean``. Rank gathers are scalar-predicated
+    selects over the sorted rows (dynamic sublane indexing doesn't
+    vectorize on the VPU)."""
     m = x.shape[0]
     c = jnp.sum(bvalid.astype(jnp.int32))
     if rule == "mean":
         return jnp.sum(x, axis=0) / jnp.maximum(c, 1).astype(jnp.float32)
-    xf = jnp.where(bvalid > 0.0, x, jnp.inf)
-    xs = jnp.sort(xf, axis=0)
-    rank = jax.lax.broadcasted_iota(jnp.int32, (m, 1), 0)
+    xs = _sort_rows(jnp.where(bvalid > 0.0, x, jnp.inf))
     if rule == "median":
-        lo = jnp.sum(jnp.where(rank == (c - 1) // 2, xs, 0.0), axis=0)
-        hi = jnp.sum(jnp.where(rank == c // 2, xs, 0.0), axis=0)
-        return 0.5 * (lo + hi)
+        lo = sum(jnp.where(r == (c - 1) // 2, row, 0.0)
+                 for r, row in enumerate(xs))
+        hi = sum(jnp.where(r == c // 2, row, 0.0)
+                 for r, row in enumerate(xs))
+        return (0.5 * (lo + hi))[0]
     if rule == "trimmed":
         t = jnp.minimum(trim, (c - 1) // 2)
-        keep = (rank >= t) & (rank < c - t)
-        kept = jnp.sum(jnp.where(keep, xs, 0.0), axis=0)
-        return kept / jnp.maximum(c - 2 * t, 1).astype(jnp.float32)
+        kept = sum(jnp.where((r >= t) & (r < c - t), row, 0.0)
+                   for r, row in enumerate(xs))
+        return kept[0] / jnp.maximum(c - 2 * t, 1).astype(jnp.float32)
     raise ValueError(rule)
 
 

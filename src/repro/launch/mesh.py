@@ -7,7 +7,7 @@ launch/dryrun.py (which sets XLA_FLAGS first) builds the 256/512-way mesh.
 from __future__ import annotations
 
 import jax
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 
 
 def make_production_mesh(*, multi_pod: bool = False,
@@ -16,14 +16,18 @@ def make_production_mesh(*, multi_pod: bool = False,
 
     ``model_parallel`` reshapes the within-pod 256 chips between the data and
     model axes (a §Perf knob: llama3-405b wants model=64). Default 16x16.
+    Axes are ``Auto`` (GSPMD propagates shardings through the program), as
+    every caller's ``PartitionSpec`` annotations assume.
     """
     per_pod = 256
     assert per_pod % model_parallel == 0
     data = per_pod // model_parallel
     if multi_pod:
         return jax.make_mesh((2, data, model_parallel),
-                             ("pod", "data", "model"))
-    return jax.make_mesh((data, model_parallel), ("data", "model"))
+                             ("pod", "data", "model"),
+                             axis_types=(AxisType.Auto,) * 3)
+    return jax.make_mesh((data, model_parallel), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
 
 
 def worker_axes(mesh) -> tuple:

@@ -112,6 +112,8 @@ def main(argv=None) -> None:
     from repro.obs import profile
     if args.profile_dir:
         profile.enable_step_markers()   # before the first backend touch
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     spec = spec_from_args(args)
     if args.spec_out:
         with open(args.spec_out, "w") as f:

@@ -67,7 +67,9 @@ def build_parser() -> argparse.ArgumentParser:
                     help="force per-cell serial execution (no seed vmap)")
     ap.add_argument("--workers", type=int, default=0,
                     help="run un-batchable cells in N pinned worker "
-                         "subprocesses (0 = in-process)")
+                         "subprocesses (0 = in-process). On a TPU host the "
+                         "workers must be pinned off the chip with "
+                         "--platform cpu: one process per chip")
     ap.add_argument("--timeout", type=float, default=None,
                     help="per-cell timeout in seconds (worker pool only)")
     ap.add_argument("--gpus", default=None,
@@ -117,6 +119,8 @@ def main(argv=None):
     if args.profile_dir:
         from repro.obs import profile
         profile.enable_step_markers()   # before the first backend touch
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     sweep = sweep_from_args(args)
     cells = list(sweep.expand())
     if args.list:

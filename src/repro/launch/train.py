@@ -150,6 +150,8 @@ def main():
     if args.profile_dir:
         # before the first backend touch (spec resolution may init jax)
         profile.enable_step_markers()
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     if args.list_components:
         for kind in ("arch", "method", "attack", "aggregator", "compressor",
                      "optimizer", "agg_mode"):

@@ -2,43 +2,30 @@
 
 ``profile_trace(dir)`` wraps a run in ``jax.profiler.trace`` so the launch
 CLIs can dump a TensorBoard-loadable device trace with ``--profile-dir``.
-``enable_step_markers()`` applies the XLA step-marker env idiom
-(``--xla_step_marker_location=1`` — mark the outer while/training step, 0
-would mark the program entry) so profiler timelines show per-step
-boundaries; it must run before the first backend touch, which is why the
-CLIs call it at parse time rather than inside the run. The flag only
-exists in TPU XLA builds — and XLA's env-flag parsing is fail-closed
-(an unknown flag aborts the process) — so it is applied only when a TPU
-runtime is detectable without initializing the backend.
+``enable_step_markers()`` sets XLA's step-marker location to the outer
+while loop (the training step; the default marks the program entry) so
+profiler timelines show per-step boundaries; it must run before the first
+backend touch, which is why the CLIs call it at parse time rather than
+inside the run. The flag belongs in ``XLA_FLAGS``, spelled with its enum
+name: jaxlib parses ``XLA_FLAGS`` on every backend and aborts the process
+on a numeric value (``=1``), and libtpu's ``LIBTPU_INIT_ARGS`` does not
+know the flag at all.
 """
 from __future__ import annotations
 
 import contextlib
-import glob
-import importlib.util
 import os
 
 
-STEP_MARKER_FLAG = "--xla_step_marker_location=1"
-
-
-def _tpu_runtime_present() -> bool:
-    """TPU detection WITHOUT touching the jax backend (which would freeze
-    XLA_FLAGS): an explicit platform request, or the libtpu wheel plus an
-    actual accelerator device node (the wheel alone proves nothing — CPU
-    images ship it and then fall back)."""
-    if "tpu" in os.environ.get("JAX_PLATFORMS", "").lower():
-        return True
-    return (importlib.util.find_spec("libtpu") is not None
-            and bool(glob.glob("/dev/accel*")))
+STEP_MARKER_FLAG = (
+    "--xla_step_marker_location=STEP_MARK_AT_TOP_LEVEL_WHILE_LOOP")
 
 
 def enable_step_markers() -> None:
     """Prepend the step-marker flag to XLA_FLAGS (idempotent). No-op once
-    the backend is initialized — call before any jax import touches it —
-    and on non-TPU builds, whose XLA rejects the flag outright."""
+    the backend is initialized — call before any jax import touches it."""
     flags = os.environ.get("XLA_FLAGS", "")
-    if "xla_step_marker_location" in flags or not _tpu_runtime_present():
+    if "xla_step_marker_location" in flags:
         return
     os.environ["XLA_FLAGS"] = (STEP_MARKER_FLAG + (" " + flags if flags
                                                   else ""))

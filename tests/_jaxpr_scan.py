@@ -7,8 +7,9 @@ audited. Used by test_norm_agg.py (fused attack phase) and
 test_wire.py (fused compressed-wire phase).
 """
 import jax
+from jax.extend.core import ClosedJaxpr, Jaxpr
 
-_JAXPR_TYPES = (jax.core.Jaxpr, jax.core.ClosedJaxpr)
+_JAXPR_TYPES = (Jaxpr, ClosedJaxpr)
 
 
 def iter_eqns(jaxpr):
@@ -20,7 +21,7 @@ def iter_eqns(jaxpr):
         for v in eqn.params.values():
             for sub in jax.tree.leaves(
                     v, is_leaf=lambda x: isinstance(x, _JAXPR_TYPES)):
-                if isinstance(sub, jax.core.ClosedJaxpr):
+                if isinstance(sub, ClosedJaxpr):
                     yield from iter_eqns(sub.jaxpr)
-                elif isinstance(sub, jax.core.Jaxpr):
+                elif isinstance(sub, Jaxpr):
                     yield from iter_eqns(sub)

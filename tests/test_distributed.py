@@ -177,6 +177,23 @@ assert err < 1e-5, err
 print("SPEC_A2A_OK", err)
 """
 
+A2A_WARMUP_SCRIPT = r"""
+import os
+os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+from repro.api import RunSpec, build
+
+spec = RunSpec(task="logreg", method="marina", n_workers=4, n_byz=1,
+               p=0.3, lr=0.3, attack="ALIE", aggregator="cm", bucket_size=2,
+               agg_mode="all_to_all", steps=4,
+               data_kwargs={"n_samples": 80, "dim": 12, "batch_size": 8})
+exp = build(spec)
+sizes = []
+exp.run(log_every=1, warmup=True,
+        callback=lambda it, state, m: sizes.append(exp.step._cache_size()))
+assert len(sizes) == 4 and len(set(sizes)) == 1, sizes
+print("A2A_WARMUP_OK", sizes)
+"""
+
 MESH_SCRIPT = r"""
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
@@ -226,6 +243,14 @@ def test_run_spec_all_to_all_matches_gspmd():
     visible devices by api.runner) must match the gspmd trajectory."""
     r = _run(SPEC_A2A_SCRIPT)
     assert "SPEC_A2A_OK" in r.stdout, r.stdout + r.stderr
+
+
+def test_all_to_all_warmup_leaves_no_compile_in_the_run():
+    """The all_to_all step hands its state back on the mesh, placed unlike
+    the start state: warm-up must compile that placement too, so no round
+    of the timed loop compiles."""
+    r = _run(A2A_WARMUP_SCRIPT)
+    assert "A2A_WARMUP_OK" in r.stdout, r.stdout + r.stderr
 
 
 def test_sparse_support_mode_trains():

@@ -79,3 +79,35 @@ def test_worker_pool_subprocess_cells(tmp_path):
         assert os.path.exists(tmp_path / f"{rid}.json")
     led = xc.Ledger(str(tmp_path / "ledger.jsonl"))
     assert led.completed() == {rid for rid, _ in cells}
+
+
+@pytest.mark.parametrize("backend,platform,workers,refused", [
+    ("cpu", None, 2, False),     # no chip anywhere
+    ("tpu", None, 2, True),      # parent holds the chip, workers want it
+    ("tpu", "cpu", 2, False),    # workers pinned off the chip
+    ("cpu", "tpu", 2, True),     # two workers would share one chip
+    ("cpu", "tpu", 1, False),    # one worker at a time owns it
+])
+def test_worker_pool_keeps_one_process_per_chip(monkeypatch, backend,
+                                                platform, workers, refused):
+    import jax
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    monkeypatch.delenv("JAX_PLATFORMS", raising=False)
+    pool = xc.WorkerPool(max_workers=workers, jax_platform=platform)
+    if refused:
+        with pytest.raises(RuntimeError, match="WorkerPool"):
+            pool.check_chip_owner()
+    else:
+        pool.check_chip_owner()
+
+
+def test_run_cells_refuses_pool_on_held_chip(monkeypatch, tmp_path):
+    """On a TPU host the pool is refused before any cell starts."""
+    import jax
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.delenv("JAX_PLATFORMS", raising=False)
+    cells = list(Sweep(RunSpec(**BASE_KW), {}).expand())
+    with pytest.raises(RuntimeError, match="one process per chip"):
+        xc.run_cells(cells, out_dir=str(tmp_path),
+                     pool=xc.WorkerPool(max_workers=2), batch=False)
+    assert not os.path.exists(tmp_path / "ledger.jsonl")

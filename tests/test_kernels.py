@@ -69,6 +69,24 @@ def test_robust_agg_tile_boundaries():
                                    atol=1e-5)
 
 
+@pytest.mark.parametrize("rule", ["median", "trimmed"])
+def test_robust_agg_nan_row_matches_sort_order(rule):
+    """No fault guard: NaN sorts last, as in the jnp oracles (jnp.sort), so
+    one NaN worker leaves the median finite; columns where the NaNs reach
+    the selected ranks give NaN on both sides."""
+    from repro.core.aggregators import coord_median, coord_trimmed_mean
+    x = jax.random.normal(jax.random.fold_in(KEY, 7), (8, 1000))
+    x = x.at[3].set(jnp.nan)                  # one NaN worker
+    x = x.at[:6, 5].set(jnp.nan)              # NaN reaches the middle ranks
+    x = x.at[1, 9].set(jnp.inf)               # +inf still sorts before NaN
+    got = robust_agg(x, rule=rule, interpret=True)
+    want = (coord_median(x) if rule == "median"
+            else coord_trimmed_mean(x, 1))
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=1e-5)
+    if rule == "median":
+        assert np.isfinite(np.delete(np.asarray(got), 5)).all()
+
+
 def test_ops_wrapper_with_permutation():
     x = jax.random.normal(KEY, (16, 512))
     out = ops.robust_agg(x, KEY, bucket_size=2, rule="median",
