@@ -1,0 +1,9 @@
+"""update_scope_ms: device self time of the step's ops under its
+``update`` scope (x - lr g, the new state and its norm), per round of the
+traced window (benchlib/spans.py)."""
+from benchlib import spans
+
+
+def read(ctx):
+    sp = spans.read(ctx)
+    return None if sp is None else sp.layer_ms_per_round("update")

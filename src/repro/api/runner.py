@@ -30,6 +30,7 @@ import jax
 
 from repro.core import tree_utils as tu
 from repro.core.engine import Method, make_method
+from repro.obs.sink import span
 
 
 # ---------------------------------------------------------------------------
@@ -270,6 +271,23 @@ def _placement(tree) -> list:
     return [getattr(a, "sharding", None) for a in jax.tree.leaves(tree)]
 
 
+def _detection_metrics(trace_host: dict) -> dict:
+    """A logged round's detection quality from its host RoundTrace, and
+    on chaos rounds the guard's rejections against the injected faults."""
+    from repro.obs import detect
+    det = detect.detection_metrics(trace_host)
+    out = {"detect_precision": det["precision"],
+           "detect_recall": det["recall"],
+           "byz_leakage": det["byz_leakage"],
+           "n_filtered": det["n_filtered"]}
+    fm = detect.fault_metrics(trace_host)
+    if fm:
+        out.update(fault_precision=fm["fault_precision"],
+                   fault_recall=fm["fault_recall"],
+                   n_fault_rejected=fm["n_rejected"])
+    return out
+
+
 def _run_experiment(exp: Experiment, *, log_every: int = 10,
                     verbose: bool = False, warmup: bool = False,
                     checkpoint: Optional[str] = None,
@@ -298,7 +316,6 @@ def _run_experiment(exp: Experiment, *, log_every: int = 10,
     step = exp.step
     step_traced = None
     if spec.trace:
-        from repro.obs import detect as obs_detect
         from repro.obs import trace as obs_trace
         step_traced = jax.jit(exp.method.step_traced)
 
@@ -330,77 +347,82 @@ def _run_experiment(exp: Experiment, *, log_every: int = 10,
     part_frac = spec.resolved_participation() / spec.n_workers
     pending_ck = []          # device arrays; synced only on log steps so the
     t0 = time.time()         # loop keeps JAX's async dispatch pipelined
-    for it in range(start, spec.steps):
-        last = it == spec.steps - 1
-        do_log = it % max(log_every, 1) == 0 or last
-        do_cb = callback is not None and (
-            (it + 1) % max(callback_every, 1) == 0 or last
-            if callback_every is not None else do_log)
-        # the telemetry twin runs only at log cadence (bit-identical
-        # trajectory, pinned by tests/test_obs.py), so the off-cadence hot
-        # path stays the untraced jaxpr
-        fn = step_traced if (step_traced is not None
-                             and (do_log or do_cb)) else step
-        state, metrics = fn(*exp.step_args(state, it, k_run))
-        rt = metrics.pop("trace", None) if spec.trace else None
-        pending_ck.append(metrics.get("c_k"))
-        if do_log or do_cb:
-            for ck in pending_ck:
-                comm_bits_total += part_frac * exp.method.round_bits(
-                    n_params, True if ck is None else bool(ck))
-            pending_ck.clear()
-            m = {k: float(v) for k, v in metrics.items()}
-            m["step"] = it
-            m["wall_s"] = round(time.time() - t0, 2)
-            m["comm_bits"] = comm_bits_total
-            m["comm_gbits"] = round(comm_bits_total / 1e9, 4)
-            trace_host = None
-            if rt is not None:
-                # the only extra sync is here, at log cadence, where the
-                # float() materialization above already fenced the device
-                trace_host = obs_trace.to_host(rt)
-                det = obs_detect.detection_metrics(trace_host)
-                m["detect_precision"] = det["precision"]
-                m["detect_recall"] = det["recall"]
-                m["byz_leakage"] = det["byz_leakage"]
-                m["n_filtered"] = det["n_filtered"]
-                fm = obs_detect.fault_metrics(trace_host)
-                if fm:                 # chaos rounds: guard-vs-injected
-                    m["fault_precision"] = fm["fault_precision"]
-                    m["fault_recall"] = fm["fault_recall"]
-                    m["n_fault_rejected"] = fm["n_rejected"]
-            if do_log:
-                history.append(m)
-                if trace_host is not None:
-                    traces.append(trace_host)
-                if sink is not None:
-                    sink.emit({"type": "round", **m})
-                    if trace_host is not None:
-                        sink.emit({"type": "trace", "step": it,
-                                   **trace_host})
-            if verbose and do_log:
-                ck = f" c_k={int(m['c_k'])}" if "c_k" in m else ""
-                print(f"  step {it:5d} loss {m['loss']:.4f} "
-                      f"|g| {m['g_norm']:.3e}{ck} "
-                      f"comm {m['comm_gbits']:.3g}Gb ({m['wall_s']}s)")
-            if do_cb and callback(it, state, m):
-                if not do_log:           # record the stop point
-                    history.append(m)
-                break                    # callback asked for early stop
-        if (checkpoint and checkpoint_every
-                and (it + 1) % checkpoint_every == 0 and not last):
-            save_checkpoint(checkpoint, state, step=int(state["step"]))
-            if verbose:
-                print(f"[run] checkpoint @ step {it + 1} -> "
-                      f"{checkpoint}.npz")
-    jax.block_until_ready(state["g"])
-    result = RunResult(spec=spec, history=history, state=state,
-                       n_params=n_params, comm_bits=comm_bits_total,
-                       wall_s=time.time() - t0, traces=traces)
+    # each round is a profiler step ("round", step_num=it) holding its
+    # feed / dispatch / log / checkpoint spans (DESIGN.md §5); of the spans
+    # only "run" reaches the sink
+    with span(sink, "run", steps=spec.steps - start):
+        for it in range(start, spec.steps):
+            with span(None, "round", step_num=it):
+                last = it == spec.steps - 1
+                do_log = it % max(log_every, 1) == 0 or last
+                do_cb = callback is not None and (
+                    (it + 1) % max(callback_every, 1) == 0 or last
+                    if callback_every is not None else do_log)
+                # the telemetry twin runs only at log cadence (bit-identical
+                # trajectory, pinned by tests/test_obs.py), so the
+                # off-cadence hot path stays the untraced jaxpr
+                fn = step_traced if (step_traced is not None
+                                     and (do_log or do_cb)) else step
+                with span(None, "feed"):
+                    args = exp.step_args(state, it, k_run)
+                with span(None, "dispatch"):
+                    state, metrics = fn(*args)
+                del args
+                rt = metrics.pop("trace", None) if spec.trace else None
+                pending_ck.append(metrics.get("c_k"))
+                if do_log or do_cb:
+                    with span(None, "log"):
+                        for ck in pending_ck:
+                            comm_bits_total += (
+                                part_frac * exp.method.round_bits(
+                                    n_params,
+                                    True if ck is None else bool(ck)))
+                        pending_ck.clear()
+                        m = {k: float(v) for k, v in metrics.items()}
+                        m["step"] = it
+                        m["wall_s"] = round(time.time() - t0, 2)
+                        m["comm_bits"] = comm_bits_total
+                        m["comm_gbits"] = round(comm_bits_total / 1e9, 4)
+                        trace_host = None
+                        if rt is not None:
+                            # the only extra sync is here, at log cadence,
+                            # where the float() materialization above
+                            # already fenced the device
+                            trace_host = obs_trace.to_host(rt)
+                            m.update(_detection_metrics(trace_host))
+                        if do_log:
+                            history.append(m)
+                            if trace_host is not None:
+                                traces.append(trace_host)
+                            if sink is not None:
+                                sink.emit({"type": "round", **m})
+                                if trace_host is not None:
+                                    sink.emit({"type": "trace", "step": it,
+                                               **trace_host})
+                        if verbose and do_log:
+                            ck = (f" c_k={int(m['c_k'])}" if "c_k" in m
+                                  else "")
+                            print(f"  step {it:5d} loss {m['loss']:.4f} "
+                                  f"|g| {m['g_norm']:.3e}{ck} "
+                                  f"comm {m['comm_gbits']:.3g}Gb "
+                                  f"({m['wall_s']}s)")
+                        if do_cb and callback(it, state, m):
+                            if not do_log:       # record the stop point
+                                history.append(m)
+                            break        # callback asked for early stop
+                if (checkpoint and checkpoint_every
+                        and (it + 1) % checkpoint_every == 0 and not last):
+                    with span(None, "checkpoint"):
+                        save_checkpoint(checkpoint, state,
+                                        step=int(state["step"]))
+                    if verbose:
+                        print(f"[run] checkpoint @ step {it + 1} -> "
+                              f"{checkpoint}.npz")
+        jax.block_until_ready(state["g"])
+        result = RunResult(spec=spec, history=history, state=state,
+                           n_params=n_params, comm_bits=comm_bits_total,
+                           wall_s=time.time() - t0, traces=traces)
     if sink is not None:
-        sink.emit({"type": "span", "name": "run",
-                   "wall_s": round(result.wall_s, 6),
-                   "steps": spec.steps - start})
         if traces:
             sink.emit({"type": "gauge", "name": "detection_summary",
                        "value": result.detection_summary()})

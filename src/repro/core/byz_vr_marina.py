@@ -35,6 +35,7 @@ from typing import Callable, Optional
 
 import jax.numpy as jnp
 
+from repro.core import tree_utils as tu
 from repro.core.aggregators import Aggregator
 from repro.core.attacks import Attack, no_attack
 from repro.core.compressors import Compressor, identity
@@ -125,6 +126,7 @@ class ByzVRMarinaConfig:
         """Workers sampled per round; n_workers when participation is off."""
         return self.n_workers if self.n_active is None else self.n_active
 
+    @tu.scoped("attack")
     def byz_mask(self):
         return jnp.arange(self.n_workers) < self.n_byz
 

@@ -34,6 +34,14 @@ Aggregation-backend dispatch (``aggregate``):
                          on-chip; ``message_phase`` additionally fuses
                          kernel-fusable attacks into the aggregation load so
                          the attacked tensor never hits HBM.
+
+Layer scopes (DESIGN.md §5): the round's work is traced under
+``jax.named_scope`` names that a device trace reads back from each op's HLO
+``op_name`` — ``grad`` (``stacked_grads``, MARINA's difference passes),
+``compress``, ``attack`` (``apply_attack``, ``fusable_attack_ctx``),
+``aggregate`` and ``update`` (``param_update`` and the new state); MARINA's
+two branches add ``full_round`` / ``diff_round`` around theirs. Scopes are
+metadata only: the step's jaxpr equations are unchanged.
 """
 from __future__ import annotations
 
@@ -53,6 +61,7 @@ AGG_BACKENDS = ("gspmd", "all_to_all", "sparse_support", "pallas")
 # shared round primitives
 # ---------------------------------------------------------------------------
 
+@tu.scoped("attack")
 def apply_attack(cfg, key, cand, mask=None, stats_valid=None):
     """cand: stacked pytree (n, ...). Returns the vectors actually 'sent'.
 
@@ -84,6 +93,7 @@ def apply_attack(cfg, key, cand, mask=None, stats_valid=None):
     return jax.tree.map(leaf, cand, means, stds)
 
 
+@tu.scoped("grad")
 def stacked_grads(loss_fn, params, batches, keys):
     """vmap(value_and_grad) over the leading worker axis of ``batches``."""
     def one(batch, key):
@@ -93,6 +103,7 @@ def stacked_grads(loss_fn, params, batches, keys):
     return jnp.mean(losses), grads
 
 
+@tu.scoped("aggregate")
 def aggregate(cfg, key, sent, valid=None):
     """Backend dispatch for line 10 (g = ARAgg(sent_1, ..., sent_n)).
 
@@ -122,6 +133,7 @@ def aggregate(cfg, key, sent, valid=None):
     raise ValueError(f"agg_mode {mode!r} not in {AGG_BACKENDS}")
 
 
+@tu.scoped("attack")
 def fusable_attack_ctx(cfg, cand, mask, stats_valid=None):
     """Build the ``sharded_agg.AttackCtx`` for a kernel-fusable omniscient
     attack (BF/ALIE/IPM via ``Attack.coord_apply``): the byzantine mask plus
@@ -416,6 +428,7 @@ def ingest_message_phase(cfg, attack_key, agg_key, cand, *, byz_mask=None,
     return aggregate(cfg, agg_key, sent)
 
 
+@tu.scoped("update")
 def param_update(cfg, params, g, opt_state):
     """x <- x - γ g (dtype-preserving, fp32 math) or cfg.optimizer.update."""
     if cfg.optimizer is None:
@@ -615,19 +628,20 @@ def make_engine_step(cfg, loss_fn, estimator: GradientEstimator,
             _PHASE_TRACE[0] = prev_flag
             _PHASE_SAMPLED[0] = prev_sampled
 
-        if sampled is not None:
-            updates = carry_unsampled_state(state, updates, sampled,
-                                            cfg.n_workers)
+        with jax.named_scope("update"):
+            if sampled is not None:
+                updates = carry_unsampled_state(state, updates, sampled,
+                                                cfg.n_workers)
 
-        if not est.update_params_first:
-            new_params, new_opt = param_update(cfg, old_params, g,
-                                               state["opt_state"])
+            if not est.update_params_first:
+                new_params, new_opt = param_update(cfg, old_params, g,
+                                                   state["opt_state"])
 
-        new_state = {**state, **updates, "params": new_params, "g": g,
-                     "opt_state": new_opt, "step": state["step"] + 1}
-        metrics = {"loss": ro.loss,
-                   **(ro.metrics or {}),
-                   "g_norm": jnp.sqrt(tu.tree_norm_sq(g))}
+            new_state = {**state, **updates, "params": new_params, "g": g,
+                         "opt_state": new_opt, "step": state["step"] + 1}
+            metrics = {"loss": ro.loss,
+                       **(ro.metrics or {}),
+                       "g_norm": jnp.sqrt(tu.tree_norm_sq(g))}
         if trace:
             metrics["trace"] = rt
         return new_state, metrics

@@ -102,24 +102,30 @@ class MarinaEstimator(GradientEstimator):
         # unchanged vs. the engine-side phase. phase_with_trace lets the
         # telemetry twin's RoundTrace escape the cond (both branches build
         # the same trace structure); on the untraced step it IS
-        # message_phase and the None slot adds nothing to the jaxpr.
+        # message_phase and the None slot adds nothing to the jaxpr. Each
+        # branch runs under its round-kind scope, so a device trace shows c_k
+        # per round, with the layer scopes inside.
+        @tu.scoped("full_round")
         def full_branch(_):
             loss, grads = stacked_grads(loss_fn, params, anchor, wkeys)
             g, rt = phase_with_trace(cfg, keys["attack"], keys["agg"],
                                      grads)
             return loss, g, rt
 
+        @tu.scoped("diff_round")
         def vr_branch(_):
-            qkeys = tu.per_worker_keys(
-                keys["q"], n, common=cfg.compressor.common_randomness)
+            with jax.named_scope("compress"):
+                qkeys = tu.per_worker_keys(
+                    keys["q"], n, common=cfg.compressor.common_randomness)
 
             def one(b, kg):
                 ln, gn = jax.value_and_grad(loss_fn)(params, b, kg)
                 _, go = jax.value_and_grad(loss_fn)(old_params, b, kg)
                 return ln, tu.tree_sub(gn, go)
 
-            losses, deltas = jax.vmap(one)(batch, wkeys)
-            loss = jnp.mean(losses)
+            with jax.named_scope("grad"):
+                losses, deltas = jax.vmap(one)(batch, wkeys)
+                loss = jnp.mean(losses)
             if wire.wire_supported(cfg, deltas):
                 # candidate = g^k + Q(delta): g^k rides as the SHARED (1, d)
                 # reconstruction base, Q(delta) as the wire payload.
@@ -128,10 +134,12 @@ class MarinaEstimator(GradientEstimator):
                 g, rt = phase_with_trace(cfg, keys["attack"], keys["agg"],
                                          wc)
                 return loss, g, rt
-            qs = jax.vmap(
-                lambda kq, t: tu.compress_tree(cfg.compressor, kq, t)
-            )(qkeys, deltas)
-            cand = jax.tree.map(lambda g0, q: g0[None] + q, state["g"], qs)
+            with jax.named_scope("compress"):
+                qs = jax.vmap(
+                    lambda kq, t: tu.compress_tree(cfg.compressor, kq, t)
+                )(qkeys, deltas)
+                cand = jax.tree.map(lambda g0, q: g0[None] + q, state["g"],
+                                    qs)
             g, rt = phase_with_trace(cfg, keys["attack"], keys["agg"],
                                      cand)
             return loss, g, rt
@@ -188,49 +196,61 @@ class MarinaSparseEstimator(MarinaEstimator):
             xf = xf.reshape(-1, blk).at[idx].set(vals)
             return xf.reshape(-1)[:d].reshape(leaf.shape).astype(leaf.dtype)
 
+        @tu.scoped("full_round")
         def full_branch(_):
             loss, grads = stacked_grads(loss_fn, params, anchor, wkeys)
             sent = apply_attack(cfg, keys["attack"], grads)
-            return loss, cfg.aggregator.tree(keys["agg"], sent)
+            with jax.named_scope("aggregate"):
+                return loss, cfg.aggregator.tree(keys["agg"], sent)
 
+        @tu.scoped("diff_round")
         def sparse_branch(_):
             # shared per-leaf supports (same key for every worker)
             g_leaves, treedef = jax.tree.flatten(state["g"])
             meta = []
-            for i, gl in enumerate(g_leaves):
-                d = gl.size
-                blk, n_units = unit_partition(d)
-                k_units = max(int(ratio * n_units), 1)
-                kk = jax.random.fold_in(keys["q"], i)
-                idx = jax.random.permutation(kk, n_units)[:k_units]
-                meta.append((blk, n_units, k_units, idx,
-                             n_units / k_units, d))
+            with jax.named_scope("compress"):
+                for i, gl in enumerate(g_leaves):
+                    d = gl.size
+                    blk, n_units = unit_partition(d)
+                    k_units = max(int(ratio * n_units), 1)
+                    kk = jax.random.fold_in(keys["q"], i)
+                    idx = jax.random.permutation(kk, n_units)[:k_units]
+                    meta.append((blk, n_units, k_units, idx,
+                                 n_units / k_units, d))
 
             def one(b, kg):
-                ln, gn = jax.value_and_grad(loss_fn)(params, b, kg)
-                _, go = jax.value_and_grad(loss_fn)(old_params, b, kg)
-                delta = tu.tree_sub(gn, go)
+                with jax.named_scope("grad"):
+                    ln, gn = jax.value_and_grad(loss_fn)(params, b, kg)
+                    _, go = jax.value_and_grad(loss_fn)(old_params, b, kg)
+                    delta = tu.tree_sub(gn, go)
                 d_leaves = jax.tree.leaves(delta)
                 vals = []
-                for (blk, nu, ku, idx, scale, d), dl in zip(meta, d_leaves):
-                    v = support_take(dl.reshape(-1).astype(jnp.float32),
-                                     idx, blk, d) * scale
-                    vals.append(v)
+                with jax.named_scope("compress"):
+                    for (blk, nu, ku, idx, scale, d), dl in zip(meta,
+                                                                d_leaves):
+                        v = support_take(dl.reshape(-1).astype(jnp.float32),
+                                         idx, blk, d) * scale
+                        vals.append(v)
                 return ln, tuple(vals)
 
             losses, dvals = jax.vmap(one)(batch, wkeys)
             # candidates on the support: g^k|support + scaled delta
             cand = []
-            for (blk, nu, ku, idx, scale, d), gl, dv in zip(
-                    meta, g_leaves, dvals):
-                base = support_take(gl.reshape(-1).astype(jnp.float32),
-                                    idx, blk, d)
-                cand.append(base[None] + dv)
+            with jax.named_scope("compress"):
+                for (blk, nu, ku, idx, scale, d), gl, dv in zip(
+                        meta, g_leaves, dvals):
+                    base = support_take(gl.reshape(-1).astype(jnp.float32),
+                                        idx, blk, d)
+                    cand.append(base[None] + dv)
             sent = apply_attack(cfg, keys["attack"], tuple(cand))
-            agg_vals = cfg.aggregator.tree(keys["agg"], sent)
-            new_leaves = [support_put(gl, m[3], m[0], av)
-                          for m, gl, av in zip(meta, g_leaves, agg_vals)]
-            return jnp.mean(losses), jax.tree.unflatten(treedef, new_leaves)
+            with jax.named_scope("aggregate"):
+                agg_vals = cfg.aggregator.tree(keys["agg"], sent)
+                new_leaves = [support_put(gl, m[3], m[0], av)
+                              for m, gl, av in zip(meta, g_leaves,
+                                                   agg_vals)]
+            with jax.named_scope("grad"):
+                loss = jnp.mean(losses)
+            return loss, jax.tree.unflatten(treedef, new_leaves)
 
         loss, g_new = lax.cond(c_k, full_branch, sparse_branch, operand=None)
         return RoundOutput(loss=loss, g_new=g_new,

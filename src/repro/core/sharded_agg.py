@@ -48,6 +48,7 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
+from repro.core import tree_utils as tu
 from repro.core.aggregators import (COORD_KERNEL_RULE, _bucketize_perm,
                                     coord_median, coord_trimmed_mean)
 
@@ -382,6 +383,7 @@ def _segments(leaves, attack_ctx):
     return segs, means, stds, splits
 
 
+@tu.scoped("aggregate")
 def tree_aggregate_pallas(cfg, key, sent, attack_ctx=None, weights=None,
                           return_info=False, valid=None):
     """Aggregate the stacked candidate pytree through the one-sweep Pallas
@@ -484,6 +486,7 @@ def tree_aggregate_pallas(cfg, key, sent, attack_ctx=None, weights=None,
     return (tree, info) if return_info else tree
 
 
+@tu.scoped("aggregate")
 def tree_aggregate_pallas_wire(cfg, key, wc, attack_ctx=None,
                                return_info=False, valid=None):
     """Wire twin of ``tree_aggregate_pallas``: the candidates arrive as a

@@ -1,8 +1,26 @@
 """Small pytree helpers shared by the trainers."""
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
+
+
+def scoped(name: str):
+    """Decorator: trace every call under ``jax.named_scope(name)``, so the
+    ops it stages carry ``name`` in their HLO ``op_name`` (the round's
+    layer and round-kind scopes, DESIGN.md §5). A fresh scope per call:
+    ``jax.named_scope`` used as a decorator reuses one context object, and
+    a nested call of the same function would restore the wrong name stack
+    on exit."""
+    def wrap(fn):
+        @functools.wraps(fn)
+        def call(*args, **kwargs):
+            with jax.named_scope(name):
+                return fn(*args, **kwargs)
+        return call
+    return wrap
 
 
 def tree_add(a, b):
@@ -80,6 +98,7 @@ def per_worker_keys(key, n, *, common: bool = False):
     return jax.vmap(lambda i: jax.random.fold_in(key, i))(jnp.arange(n))
 
 
+@scoped("compress")
 def compress_tree(compressor, key, tree):
     """Apply an unbiased compressor leaf-wise (block compression). Each leaf
     gets its own fold_in'd key so RandK supports differ across leaves."""

@@ -137,6 +137,7 @@ def _pack_fn(compressor):
             "bf16": quantize.pack_bf16}[fmt]
 
 
+@tu.scoped("compress")
 def pack_candidates(compressor, qkeys, stacked, *, base=None,
                     base_shared: bool = False) -> WireCandidates:
     """Pack the to-be-compressed stacked tree into its wire payload.
@@ -188,6 +189,7 @@ def decoded_payload(wc: WireCandidates):
     return jax.tree.unflatten(wc.treedef, outs)
 
 
+@tu.scoped("aggregate")
 def reconstruct(wc: WireCandidates):
     """The dense candidate tree the oracle path would materialize:
     decode → candidate dtype → + base → candidate dtype (leaf-dtype add,
@@ -407,15 +409,16 @@ def wire_message_phase(cfg, attack_key, agg_key, wc: WireCandidates,
                                                return_info=return_info,
                                                valid=valid), valid)
     if cfg.attack.coord_apply is not None:
-        mask = cfg.byz_mask()
-        means = stds = None
-        if cfg.attack.needs_mean or cfg.attack.needs_std:
-            good = ~mask if valid is None else ~mask & valid
-            means, stds = wire_stats(wc, good, sanitize=guard)
-            if not cfg.attack.needs_std:
-                stds = None
-        ctx = AttackCtx(fn=cfg.attack.coord_apply, mask=mask,
-                        means=means, stds=stds)
+        with jax.named_scope("attack"):
+            mask = cfg.byz_mask()
+            means = stds = None
+            if cfg.attack.needs_mean or cfg.attack.needs_std:
+                good = ~mask if valid is None else ~mask & valid
+                means, stds = wire_stats(wc, good, sanitize=guard)
+                if not cfg.attack.needs_std:
+                    stds = None
+            ctx = AttackCtx(fn=cfg.attack.coord_apply, mask=mask,
+                            means=means, stds=stds)
         return _ret(tree_aggregate_pallas_wire(cfg, agg_key, wc,
                                                attack_ctx=ctx,
                                                return_info=return_info,

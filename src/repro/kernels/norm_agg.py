@@ -259,6 +259,7 @@ def pair_gram(x, w_mat=None, mask=None, good_mean=None, good_std=None,
         in_specs=specs,
         out_specs=pl.BlockSpec((m, m), lambda i: (0, 0)),
         out_shape=jax.ShapeDtypeStruct((m, m), jnp.float32),
+        name="pair_gram",
         interpret=interpret,
     )(*vals)
 
@@ -303,6 +304,7 @@ def rfa_iter(x, w, w_mat=None, mask=None, good_mean=None, good_std=None,
                    pl.BlockSpec((m, 1), lambda i: (0, 0))),
         out_shape=(jax.ShapeDtypeStruct((1, dp), jnp.float32),
                    jax.ShapeDtypeStruct((m, 1), jnp.float32)),
+        name="rfa_iter",
         interpret=resolve_interpret(interpret),
     )(*vals)
     return z[0, :d], sq[:, 0]
@@ -336,6 +338,7 @@ def weighted_sum(x, w, mask=None, good_mean=None, good_std=None, valid=None,
         in_specs=specs,
         out_specs=pl.BlockSpec((1, tile), lambda i: (0, i)),
         out_shape=jax.ShapeDtypeStruct((1, dp), jnp.float32),
+        name="weighted_sum",
         interpret=resolve_interpret(interpret),
     )(*vals)
     return out[0, :d]
@@ -520,6 +523,7 @@ def pair_gram_blocked(x, *, tile_n: int = DEFAULT_TILE_N,
                   pl.BlockSpec((tn, tile), lambda i, j, k: (j, k))],
         out_specs=pl.BlockSpec((tn, tn), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((mp, mp), jnp.float32),
+        name="pair_gram_blocked",
         interpret=interpret,
     )(xp, xp)
     return g[:m, :m]
@@ -555,6 +559,7 @@ def sqdist_to_blocked(x, z, *, tile_n: int = DEFAULT_TILE_N,
                   pl.BlockSpec((1, tile), lambda i, k: (0, k))],
         out_specs=pl.BlockSpec((tn, 1), lambda i, k: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((mp, 1), jnp.float32),
+        name="sqdist_to_blocked",
         interpret=resolve_interpret(interpret),
     )(xp, zp)
     return sq[:m, 0]
@@ -589,6 +594,7 @@ def weighted_sum_blocked(x, w, *, tile_n: int = DEFAULT_TILE_N,
                   pl.BlockSpec((tn, 1), lambda k, i: (i, 0))],
         out_specs=pl.BlockSpec((1, tile), lambda k, i: (0, k)),
         out_shape=jax.ShapeDtypeStruct((1, dp), jnp.float32),
+        name="weighted_sum_blocked",
         interpret=resolve_interpret(interpret),
     )(xp, wp)
     return out[0, :d]

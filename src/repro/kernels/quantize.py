@@ -97,6 +97,7 @@ def block_quantize(x, u, *, levels: int = 4, block: int = 256,
         in_specs=[spec, spec],
         out_specs=spec,
         out_shape=jax.ShapeDtypeStruct((dp // block, block), jnp.float32),
+        name="block_quantize",
         interpret=resolve_interpret(interpret),
     )(x.reshape(-1, block), u.reshape(-1, block))
     return out.reshape(-1)[:d]
@@ -234,6 +235,7 @@ def topk_pool(xf, cp: int, *, tile: int, interpret=None):
                    pl.BlockSpec((1, 1, cp), lambda i: (i, 0, 0))),
         out_shape=(jax.ShapeDtypeStruct((t_count, 1, cp), jnp.float32),
                    jax.ShapeDtypeStruct((t_count, 1, cp), jnp.int32)),
+        name="topk_pool",
         interpret=resolve_interpret(interpret),
     )(xp)
 

@@ -187,6 +187,7 @@ def robust_agg(x, bucket_matrix=None, mask=None, good_mean=None,
         in_specs=specs,
         out_specs=pl.BlockSpec((tile,), lambda i: (i,)),
         out_shape=jax.ShapeDtypeStruct((dp,), jnp.float32),
+        name="robust_agg",
         interpret=resolve_interpret(interpret),
     )(*vals)
     return out[:d]

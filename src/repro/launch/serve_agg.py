@@ -110,8 +110,6 @@ def spec_from_args(args) -> ServeSpec:
 def main(argv=None) -> None:
     args = build_parser().parse_args(argv)
     from repro.obs import profile
-    if args.profile_dir:
-        profile.enable_step_markers()   # before the first backend touch
     from repro.launch.compile_cache import enable_compile_cache
     enable_compile_cache()
     spec = spec_from_args(args)

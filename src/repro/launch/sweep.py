@@ -116,9 +116,6 @@ def sweep_from_args(args) -> Sweep:
 
 def main(argv=None):
     args = build_parser().parse_args(argv)
-    if args.profile_dir:
-        from repro.obs import profile
-        profile.enable_step_markers()   # before the first backend touch
     from repro.launch.compile_cache import enable_compile_cache
     enable_compile_cache()
     sweep = sweep_from_args(args)

@@ -147,9 +147,6 @@ def spec_from_args(args) -> RunSpec:
 def main():
     args = build_parser().parse_args()
     from repro.obs import profile
-    if args.profile_dir:
-        # before the first backend touch (spec resolution may init jax)
-        profile.enable_step_markers()
     from repro.launch.compile_cache import enable_compile_cache
     enable_compile_cache()
     if args.list_components:

@@ -10,10 +10,13 @@ Four pieces, shared by ``api.runner``, ``repro.exec`` and ``repro.serve``:
                 truth byzantine mask (filter precision/recall, influence
                 leakage).
 * ``sink``    — the ``MetricSink`` event protocol (JSONL stream, in-memory
-                ring, fan-out) plus wall-clock spans that fence with
-                ``block_until_ready`` only at log-cadence boundaries.
-* ``profile`` — ``jax.profiler`` trace context + the XLA step-marker env
-                idiom, wired into the launch CLIs as ``--profile-dir``.
+                ring, fan-out) plus ``span``: a wall-clock section that is
+                also a ``jax.profiler.TraceAnnotation`` on the device
+                trace's clock, and never forces a device sync.
+* ``profile`` — ``jax.profiler`` trace context, wired into the launch CLIs
+                as ``--profile-dir``; round boundaries come from the
+                runner's ``StepTraceAnnotation`` spans, and the names of
+                the loop's spans and the step's device scopes.
 """
 from repro.obs.detect import detection_metrics, filtered_mask, summarize
 from repro.obs.sink import (FanoutSink, JsonlSink, MetricSink, NullSink,

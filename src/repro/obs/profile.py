@@ -1,34 +1,25 @@
-"""Span profiling: jax.profiler traces + the XLA step-marker idiom.
+"""Device traces for the launch CLIs, and the names a trace carries.
 
 ``profile_trace(dir)`` wraps a run in ``jax.profiler.trace`` so the launch
 CLIs can dump a TensorBoard-loadable device trace with ``--profile-dir``.
-``enable_step_markers()`` sets XLA's step-marker location to the outer
-while loop (the training step; the default marks the program entry) so
-profiler timelines show per-step boundaries; it must run before the first
-backend touch, which is why the CLIs call it at parse time rather than
-inside the run. The flag belongs in ``XLA_FLAGS``, spelled with its enum
-name: jaxlib parses ``XLA_FLAGS`` on every backend and aborts the process
-on a numeric value (``=1``), and libtpu's ``LIBTPU_INIT_ARGS`` does not
-know the flag at all.
+The trace needs no compiler flag: the program names its own parts.
+
+* Host spans (``obs.sink.span``, a ``jax.profiler.TraceAnnotation`` each):
+  the training loop (``api.runner``) runs every round under a
+  ``StepTraceAnnotation`` named ``round`` (its ``step_num`` is the round
+  index), with ``feed``, ``dispatch``, ``log`` and ``checkpoint`` inside.
+* Device scopes (``jax.named_scope``, read back from each op's HLO
+  ``op_name``): ``LAYER_SCOPES`` inside the round step, and MARINA's
+  ``ROUND_SCOPES`` around the two branches of its coin.
 """
 from __future__ import annotations
 
 import contextlib
 import os
 
-
-STEP_MARKER_FLAG = (
-    "--xla_step_marker_location=STEP_MARK_AT_TOP_LEVEL_WHILE_LOOP")
-
-
-def enable_step_markers() -> None:
-    """Prepend the step-marker flag to XLA_FLAGS (idempotent). No-op once
-    the backend is initialized — call before any jax import touches it."""
-    flags = os.environ.get("XLA_FLAGS", "")
-    if "xla_step_marker_location" in flags:
-        return
-    os.environ["XLA_FLAGS"] = (STEP_MARKER_FLAG + (" " + flags if flags
-                                                  else ""))
+LAYER_SCOPES = ("grad", "compress", "attack", "aggregate", "update")
+ROUND_SCOPES = ("full_round", "diff_round")
+LOOP_SPANS = ("round", "feed", "dispatch", "log", "checkpoint")
 
 
 @contextlib.contextmanager
@@ -51,4 +42,5 @@ def add_cli_args(ap) -> None:
                          "one JSON line each — the obs.sink stream")
     ap.add_argument("--profile-dir", metavar="DIR",
                     help="dump a jax.profiler device trace here "
-                         "(TensorBoard-loadable) with XLA step markers")
+                         "(TensorBoard-loadable); round boundaries come "
+                         "from the loop's StepTraceAnnotation spans")

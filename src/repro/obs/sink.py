@@ -28,7 +28,9 @@ import contextlib
 import json
 import math
 import time
-from typing import Protocol, runtime_checkable
+from typing import Optional, Protocol, runtime_checkable
+
+import jax
 
 
 @runtime_checkable
@@ -114,13 +116,21 @@ class TagSink:
 
 
 @contextlib.contextmanager
-def span(sink, name: str, **fields):
-    """Wall-clock a section and emit one span event on exit. The caller is
-    responsible for fencing (block_until_ready) if device work must be
-    included — and should only do so at log-cadence boundaries."""
+def span(sink, name: str, *, step_num: Optional[int] = None, **fields):
+    """A named section: always a ``jax.profiler.TraceAnnotation`` of
+    ``name`` (a ``StepTraceAnnotation`` when ``step_num`` is given), which
+    puts the section on the device trace's clock when a profiler is active
+    and costs a flag check when none is; and, when ``sink`` is given, one
+    wall-clock span event on exit. The caller is responsible for fencing
+    (block_until_ready) if device work must be included — and should only
+    do so at log-cadence boundaries."""
+    annotation = (jax.profiler.TraceAnnotation(name) if step_num is None
+                  else jax.profiler.StepTraceAnnotation(name,
+                                                        step_num=step_num))
     t0 = time.perf_counter()
     try:
-        yield
+        with annotation:
+            yield
     finally:
         if sink is not None:
             sink.emit({"type": "span", "name": name,
