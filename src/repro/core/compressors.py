@@ -39,10 +39,12 @@ all-gather can physically move only K values (see core/byz_vr_marina.py).
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Callable, Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 
 
@@ -116,8 +118,56 @@ def identity() -> Compressor:
     )
 
 
-_MAX_UNITS = 1 << 22     # selection-unit cap: keeps RNG/scatter sizes int32-safe
+_MAX_UNITS = 1 << 22     # selection-unit cap: keeps RNG/sort sizes int32-safe
                          # even under a 32-way worker vmap on 1e11-param leaves
+
+
+def shuffle_rounds(n: int) -> int:
+    """Sort rounds ``jax.random.permutation`` makes over ``n`` items: JAX's
+    ``_shuffle`` stop rule, ceil(3 ln n / ln(2^32 - 1)), computed as it does."""
+    return int(np.ceil(3 * np.log(max(1, n)) / np.log(np.iinfo(np.uint32).max)))
+
+
+def smallest_k(bits, k: int):
+    """bool mask of the k smallest of uint32 ``bits``, ties broken by position
+    (what a stable sort keeps), with no sort: a 32-step bitwise search finds
+    the k-th smallest value t, one compare-and-count pass a step."""
+    def step(i, t):
+        c = t | (jnp.uint32(1) << (31 - i).astype(jnp.uint32))
+        return jnp.where(jnp.sum(bits < c, dtype=jnp.int32) < k, c, t)
+
+    t = lax.fori_loop(0, 32, step, jnp.uint32(0))
+    below = bits < t
+    tie = bits == t
+    room = k - jnp.sum(below, dtype=jnp.int32)
+    return below | (tie & (jnp.cumsum(tie, dtype=jnp.int32) <= room))
+
+
+@functools.partial(jax.jit, static_argnums=(1, 2))
+def randk_mask(key, n_units: int, k_units: int):
+    """bool[n_units] marking ``jax.random.permutation(key, n_units)[:k_units]``,
+    bit for bit, without the last shuffle sort and without a scatter.
+
+    Draws the bits ``_shuffle`` draws: rounds 1..R-1 sort the units by fresh
+    keys as it does; round R's kept positions are the k smallest keys
+    (``smallest_k``); one unstable sort of (unit << 1 | kept) by unit puts the
+    kept bits back in unit order (each unit occurs once)."""
+    assert 0 < k_units <= n_units < 1 << 31, (n_units, k_units)
+    rounds = shuffle_rounds(n_units)
+    if rounds == 0:
+        return jnp.ones((n_units,), bool)
+    units = jnp.arange(n_units)   # int32, as _shuffle's: XLA reuses it as the
+                                  # stable sort's tie-break iota in round 1
+    for _ in range(rounds - 1):
+        key, sub = jax.random.split(key)
+        _, units = lax.sort_key_val(
+            jax.random.bits(sub, (n_units,), jnp.uint32), units)
+    _, sub = jax.random.split(key)
+    kept = smallest_k(jax.random.bits(sub, (n_units,), jnp.uint32), k_units)
+    if rounds == 1:
+        return kept
+    packed = (units.astype(jnp.uint32) << 1) | kept.astype(jnp.uint32)
+    return (lax.sort(packed, is_stable=False) & 1).astype(bool)
 
 
 def rand_k(ratio: float = 0.1, *, common_randomness: bool = False) -> Compressor:
@@ -141,8 +191,7 @@ def rand_k(ratio: float = 0.1, *, common_randomness: bool = False) -> Compressor
         n_units = -(-d // blk)
         k_units = max(int(ratio * n_units), 1)
         scale = n_units / k_units
-        perm = jax.random.permutation(key, n_units)
-        mask = jnp.zeros((n_units,), bool).at[perm[:k_units]].set(True)
+        mask = randk_mask(key, n_units, k_units)
         if blk == 1:
             out = jnp.where(mask.reshape(shape), x * scale, 0)
             return out.astype(x.dtype)

@@ -1,4 +1,6 @@
 """Unit tests for unbiased compression operators (Def. 2.2)."""
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -7,7 +9,9 @@ import pytest
 from repro.core.compressors import (INT8_LEVELS, _int8_decode, _int8_encode,
                                     bf16_cast, identity, int8_quantization,
                                     l2_dithering, natural_compression,
-                                    rand_k, sign_compressor, top_k)
+                                    rand_k, randk_mask, shuffle_rounds,
+                                    sign_compressor, smallest_k, top_k,
+                                    unit_partition)
 
 KEY = jax.random.PRNGKey(7)
 
@@ -144,6 +148,73 @@ def test_huge_leaf_block_selection():
     assert abs(float(q.mean()) - 1.0) < 0.01
     frac = float((q != 0).mean())
     assert abs(frac - 0.5) < 0.01
+
+
+def _permutation_mask(key, n, k):
+    """RandK's kept units as ``jax.random.permutation`` defines them."""
+    return jnp.zeros((n,), bool).at[jax.random.permutation(key, n)[:k]].set(
+        True)
+
+
+# n at every boundary of the shuffle's round count (0 | 1 | 2 | 3 rounds) and
+# mamba2-130m's smaller leaf sizes; the 3-round size runs on two keys only.
+@pytest.mark.parametrize("typed", [False, True], ids=["raw_key", "typed_key"])
+@pytest.mark.parametrize("n,rounds", [
+    (1, 0), (2, 1), (576, 1), (1625, 1), (1626, 2), (18_432, 2),
+    (172_032, 2), (2_642_246, 3)])
+def test_randk_mask_matches_permutation(n, rounds, typed):
+    assert shuffle_rounds(n) == rounds
+    k = max(int(0.1 * n), 1)
+    base = jax.random.key(11) if typed else jax.random.PRNGKey(11)
+    keys = jax.vmap(lambda i: jax.random.fold_in(base, i))(
+        jnp.arange(2 if rounds == 3 else 8))
+    got = jax.jit(jax.vmap(lambda kk: randk_mask(kk, n, k)))(keys)
+    want = jax.jit(jax.vmap(lambda kk: _permutation_mask(kk, n, k)))(keys)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    assert (np.asarray(got).sum(axis=1) == k).all()
+
+
+@pytest.mark.parametrize("n,hi,k", [
+    (100, 3, 40), (1000, 1, 999), (1000, 4, 1), (50, 0, 7), (64, 2**32 - 1, 64),
+    (300, 2**32 - 1, 150)])
+def test_smallest_k_breaks_ties_by_position(n, hi, k):
+    """Keys from a narrow range tie at the threshold: the kept set is the
+    first k of a stable argsort, the set the last shuffle round keeps."""
+    rng = np.random.default_rng(n + k)
+    bits = rng.integers(0, hi, n, dtype=np.uint32, endpoint=True)
+    want = np.zeros(n, bool)
+    want[np.argsort(bits, kind="stable")[:k]] = True
+    got = jax.jit(smallest_k, static_argnums=1)(jnp.asarray(bits), k)
+    np.testing.assert_array_equal(np.asarray(got), want)
+
+
+def _sort_operand_counts(stablehlo: str):
+    return sorted(len(ops.split(",")) for ops in
+                  re.findall(r'"?stablehlo\.sort"?\(([^)]*)\)', stablehlo))
+
+
+def test_randk_lowered_draw_has_no_scatter():
+    """A 3-round leaf (about 2^22 units) lowers to the two key-value sorts of
+    the first shuffle rounds and one single-operand sort, with no scatter."""
+    x = jax.ShapeDtypeStruct((4_118_938,), jnp.float32)
+    text = jax.jit(rand_k(0.1).compress).lower(KEY, x).as_text()
+    assert _sort_operand_counts(text) == [1, 2, 2]
+    assert "scatter" not in text
+
+
+def test_huge_leaf_blocked_3_round_draw_matches_permutation():
+    """6M coordinates -> blocks of 2, 3M units, 3 shuffle rounds: the dense
+    output is the one the permutation formula gives."""
+    d, ratio = 6_000_000, 0.1
+    blk, n_units = unit_partition(d)
+    assert (blk, n_units, shuffle_rounds(n_units)) == (2, 3_000_000, 3)
+    k_units = int(ratio * n_units)
+    x = jax.random.normal(KEY, (d,))
+    mask = _permutation_mask(KEY, n_units, k_units)
+    xf = x.reshape(n_units, blk)
+    want = jnp.where(mask[:, None], xf * (n_units / k_units), 0).reshape(-1)
+    got = jax.jit(rand_k(ratio).compress)(KEY, x)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
 
 # ---------------------------------------------------------------------------
