@@ -14,10 +14,17 @@ do not change when a later change fuses, tiles or reorders the program.
   ``anchor_batches`` times the minibatch.
 * The aggregation's least HBM traffic: one read of the (n, d) candidate
   stack and one write of the d-vector aggregate, in the parameters' dtype.
+
+A configuration whose layers this generic count does not know (latent
+attention, experts, ...) gives its own count: its module
+``configs/<name>.py`` defines ``sequence_pass_flops(arch, seq_len)`` by
+the same accounting, and ``round_flops`` uses it.
 """
 from __future__ import annotations
 
 import jax.numpy as jnp
+
+from benchlib.harness import BenchError
 
 ATTENTION = ("attention", "sliding_window")
 
@@ -42,7 +49,11 @@ def matmul_params(arch: dict) -> tuple:
             hkv = arch["num_kv_heads"] * arch["head_dim"]
             layers += 2 * d * hq + 2 * d * hkv + 3 * d * arch["d_ff"]
         else:
-            raise ValueError(f"no FLOP count for layer kind {kind!r}")
+            raise BenchError(
+                f"configuration {arch.get('name')!r}: the generic FLOP count "
+                f"does not know layer kind {kind!r}; its module "
+                "configs/<name>.py must define sequence_pass_flops(arch, "
+                "seq_len)")
     head = arch.get("num_codebooks", 1) * d * arch["vocab_size"]
     return layers, head
 
@@ -58,9 +69,12 @@ def sequence_pass_flops(arch: dict, seq_len: int) -> float:
             + 12.0 * n_attn * s_all * d_attn * s_all)
 
 
-def round_flops(arch: dict, traffic: dict, full: bool) -> float:
+def round_flops(arch: dict, traffic: dict, full: bool, model=None) -> float:
+    """Model FLOPs of one round; ``model`` is the configuration's module,
+    whose own ``sequence_pass_flops`` is used where it defines one."""
     seqs = traffic["n_workers"] * traffic["per_worker_batch"]
-    per_seq = sequence_pass_flops(arch, traffic["seq_len"])
+    count = getattr(model, "sequence_pass_flops", sequence_pass_flops)
+    per_seq = count(arch, traffic["seq_len"])
     if full:
         return traffic["anchor_batches"] * seqs * per_seq
     return 2 * seqs * per_seq

@@ -199,25 +199,40 @@ def seed_keys(seed: int):
     return spec_seed, k_init, k_run, jax.random.fold_in(root, DATA_SALT)
 
 
-def build_program(cell: Cell, spec_seed: int):
-    from repro.api import RunSpec, build, resolve_agg_mode
+# traffic keys the harness reads itself: the batch's shape (the program
+# gets it through ``data_kwargs``), the agg_mode to resolve for the
+# platform, and the anchor batch of the benchmark's own feed
+DATA_KEYS = ("seq_len", "per_worker_batch", "remat")
+HARNESS_KEYS = DATA_KEYS + ("agg_mode", "anchor_batches")
+# RunSpec fields the harness sets: a traffic file may not name them
+SPEC_OWNED = ("task", "arch", "steps", "seed", "data_kwargs")
+
+
+def run_spec(cell: Cell, spec_seed: int):
+    """The cell's ``RunSpec``: every traffic key other than the harness's
+    own names a ``RunSpec`` field and is passed as it is; any other key is
+    an error, so that a misspelt key cannot run as the default."""
+    from repro.api import RunSpec, resolve_agg_mode
     from repro.configs.base import ArchConfig, register
     a = dict(cell.arch)
     a["block_pattern"] = tuple(a["block_pattern"])
     register(ArchConfig(**a))
     t = cell.traffic
-    spec = RunSpec(
-        task="lm", arch=a["name"], method=t["method"],
-        n_workers=t["n_workers"], n_byz=t["n_byz"], attack=t["attack"],
-        attack_kwargs=t["attack_kwargs"], aggregator=t["aggregator"],
-        bucket_size=t["bucket_size"],
+    fields = {f.name for f in dataclasses.fields(RunSpec)} - set(SPEC_OWNED)
+    unknown = sorted(set(t) - set(HARNESS_KEYS) - fields)
+    if unknown:
+        raise BenchError(f"traffic of {cell.name!r}: {unknown} name no "
+                         "RunSpec field the traffic may set")
+    passed = {k: v for k, v in t.items() if k not in HARNESS_KEYS}
+    return RunSpec(
+        task="lm", arch=a["name"], steps=CHECK_ROUNDS, seed=spec_seed,
         agg_mode=resolve_agg_mode(t["agg_mode"]),
-        compressor=t["compressor"], compressor_kwargs=t["compressor_kwargs"],
-        p=t["p"], lr=t["lr"], steps=CHECK_ROUNDS, seed=spec_seed,
-        data_kwargs={"seq_len": t["seq_len"],
-                     "per_worker_batch": t["per_worker_batch"],
-                     "remat": t["remat"]})
-    return build(spec)
+        data_kwargs={k: t[k] for k in DATA_KEYS}, **passed)
+
+
+def build_program(cell: Cell, spec_seed: int):
+    from repro.api import build
+    return build(run_spec(cell, spec_seed))
 
 
 def same_layout(a, b) -> bool:

@@ -51,9 +51,10 @@ def reduce(tdir, hlo_text, cell, rounds, coins, window_s, peaks):
     last = max(max(e for _, e, *_ in evs) for evs in dev.values())
     n_full = sum(coins)
     t = cell.traffic
-    window_flops = (n_full * flops.round_flops(cell.arch, t, True)
-                    + (rounds - n_full) * flops.round_flops(cell.arch, t,
-                                                            False))
+    window_flops = (
+        n_full * flops.round_flops(cell.arch, t, True, cell.model)
+        + (rounds - n_full) * flops.round_flops(cell.arch, t, False,
+                                                cell.model))
     n_params = _n_params(cell)
     ctx = Context(layer_ns=layer_ns, rounds=rounds, full_rounds=n_full,
                   window_s=window_s, busy_s=busy_ns / 1e9,

@@ -21,7 +21,13 @@ batch keys (a run drives a chosen subsequence of the schedule's rounds), and the
 folds ``i`` into its stream, and RandK folds in the leaf's index.
 
 Everything is float32, worker by worker and leaf by leaf, so that it fits
-on the chip beside nothing else.
+on the chip beside nothing else: each worker's gradient goes to host
+memory as it is made, and the aggregation puts one leaf's (n, ...) stack
+on the device at a time.
+
+It models Byz-VR-MARINA with ALIE, CM with bucketing, and the identity or
+RandK compressor; it refuses a traffic mix that asks for anything else,
+rather than compare the program with a round it does not run.
 """
 from __future__ import annotations
 
@@ -31,10 +37,37 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from benchlib.harness import BenchError
 from benchlib.refops import make_policy, round_to
 
 RNG = ("bern", "grad", "q", "attack", "agg")
 MAX_UNITS = 1 << 22
+# the rules it models, and for each the keyword arguments it reads
+MODELLED = {"method": {"marina": ()}, "aggregator": {"cm": ()},
+            "attack": {"ALIE": ("z",)},
+            "compressor": {"identity": (), "randk": ("ratio",)}}
+# traffic keys that set sizes and rates it reads, or that do not change
+# the round's arithmetic (remat, the aggregation backend)
+PLAIN_KEYS = ("n_workers", "n_byz", "bucket_size", "p", "lr", "seq_len",
+              "per_worker_batch", "anchor_batches", "remat", "agg_mode")
+
+
+def check_modelled(traffic: dict) -> None:
+    """Raise ``BenchError`` unless the reference models ``traffic``."""
+    for key, rules in MODELLED.items():
+        rule = traffic.get(key)
+        if rule not in rules:
+            raise BenchError(f"the reference does not model {key} {rule!r}")
+        kw = key + "_kwargs"
+        extra = sorted(set(traffic.get(kw, {})) - set(rules[rule]))
+        if extra:
+            raise BenchError(f"the reference does not model {kw} {extra} "
+                             f"of {rule!r}")
+    extra = sorted(set(traffic) - set(MODELLED) - set(PLAIN_KEYS)
+                   - {k + "_kwargs" for k in MODELLED})
+    if extra:
+        raise BenchError(f"the reference does not model traffic keys "
+                         f"{extra}")
 
 
 def randk(key, x, ratio):
@@ -77,7 +110,8 @@ def _spread(per_worker, n_byz):
     """Per leaf: the norm of the good workers' mean gradient and the mean
     of their gradients' norms (how far the aggregate's inputs cancel)."""
     good = per_worker[n_byz:]
-    mean = [_leaf_norms([sum(w[j] for w in good) / len(good)])[0]
+    mean = [_leaf_norms([sum(jnp.asarray(w[j]) for w in good)
+                         / len(good)])[0]
             for j in range(len(good[0]))]
     each = np.mean([_leaf_norms(w) for w in good], axis=0)
     return {"mean_grad": mean, "worker_grad": [float(v) for v in each]}
@@ -98,6 +132,7 @@ class Reference:
 
     def __init__(self, model, arch: dict, traffic: dict, precision="f32",
                  batch_fault=None):
+        check_modelled(traffic)
         self.arch, self.t = arch, traffic
         self.store = jnp.dtype(arch["dtype"])
         policy = make_policy(precision)
@@ -129,15 +164,15 @@ class Reference:
         return jax.tree.map(lambda a: a[i], batch)
 
     def _aggregate(self, per_worker, k_agg, base=None, k_q=None):
-        """per_worker: n lists of leaves -> aggregated leaves. Consumes
-        ``per_worker`` leaf by leaf."""
+        """per_worker: n lists of leaves in host memory -> aggregated
+        leaves on the device. Consumes ``per_worker`` leaf by leaf."""
         n = len(per_worker)
         perm = jax.random.permutation(k_agg, n)
         out = []
         for j in range(len(per_worker[0])):
             rows = []
             for i in range(n):
-                v = per_worker[i][j]
+                v = jnp.asarray(per_worker[i][j])
                 per_worker[i][j] = None
                 if base is not None:
                     if self._q is not None:
@@ -154,7 +189,7 @@ class Reference:
         for i in range(self.t["n_workers"]):
             ln, g = self._vg(x, self._worker(anchor, i))
             losses.append(ln)
-            per_worker.append(jax.tree.leaves(g))
+            per_worker.append(jax.device_get(jax.tree.leaves(g)))
         if spread is not None:
             spread.update(_spread(per_worker, self.t["n_byz"]))
         return float(np.mean(jax.device_get(losses))), self._aggregate(
@@ -165,7 +200,7 @@ class Reference:
         for i in range(self.t["n_workers"]):
             ln, dl = self._vg_diff(xn, xo, self._worker(mb, i))
             losses.append(ln)
-            per_worker.append(jax.tree.leaves(dl))
+            per_worker.append(jax.device_get(jax.tree.leaves(dl)))
         return float(np.mean(jax.device_get(losses))), self._aggregate(
             per_worker, k_agg, base=g, k_q=k_q)
 

@@ -11,26 +11,33 @@ from benchlib.refround import Reference
 from tiny import tiny_cell
 
 
-def run(fault=None, seed=2):
-    return harness.run(tiny_cell(), seed, 0.5, False,
+# the tiny cell's block with each mamba2 traffic mix: RandK and none
+WORKLOADS = ["mamba2-130m.vrmarina-randk", "mamba2-130m.vrmarina-uncompressed"]
+
+
+def run(workload, fault=None, seed=2):
+    return harness.run(tiny_cell(workload), seed, 0.5, False,
                        t_process=0.0, require_tpu=False, fault=fault)
 
 
-def test_sound_run_is_correct():
-    out = run()
+@pytest.mark.parametrize("workload", WORKLOADS)
+def test_sound_run_is_correct(workload):
+    out = run(workload)
     assert out["correct"], out["checks"]
     assert list(out)[-1] == "checks"
     assert out["attempted"] >= 2 and out["failed"] == 0
 
 
+@pytest.mark.parametrize("workload", WORKLOADS)
 @pytest.mark.parametrize("fault", ["unchanged_state", "half_batch"])
-def test_planted_fault_is_not_correct(fault):
-    out = run(fault)
+def test_planted_fault_is_not_correct(fault, workload):
+    out = run(workload, fault)
     assert not out["correct"], out["checks"]
 
 
-def test_control_is_not_correct():
-    cell = tiny_cell()
+@pytest.mark.parametrize("workload", WORKLOADS)
+def test_control_is_not_correct(workload):
+    cell = tiny_cell(workload)
     init = jax.jit(lambda k: cell.model.init_params(k, cell.arch))
     ref = Reference(cell.model, cell.arch, cell.traffic)
     control = Reference(cell.model, cell.arch, cell.traffic, "fp8")

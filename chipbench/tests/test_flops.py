@@ -2,8 +2,12 @@
 hand from the configurations' published sizes."""
 import json
 import os
+import types
 
-from benchlib import flops
+import pytest
+
+from benchlib import flops, harness
+from tiny import load
 
 CONFIGS = os.path.join(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))), "configs")
@@ -62,8 +66,41 @@ def test_aggregation_bytes():
 
 def test_mamba2_130m_parameter_count():
     import jax
-    from benchlib import harness
     cell = harness.load_cell("mamba2-130m.vrmarina-randk")
     shapes = jax.eval_shape(
         lambda k: cell.model.init_params(k, cell.arch), jax.random.PRNGKey(0))
     assert sum(int(x.size) for x in jax.tree.leaves(shapes)) == 128_940_480
+
+
+@pytest.mark.parametrize("full", [False, True])
+@pytest.mark.parametrize("workload,want", [
+    ("mamba2-130m.vrmarina-randk", 1_581_596_540_928),
+    ("musicgen-medium.vrmarina-uncompressed", 20_979_327_172_608),
+    ("mamba2-130m.vrmarina-uncompressed", 1_581_596_540_928),
+])
+def test_round_flops_of_each_cell(workload, want, full):
+    # what step_mfu reads: the cell's own traffic and module
+    cell = load(workload)
+    assert flops.round_flops(cell.arch, cell.traffic, full,
+                             cell.model) == want
+
+
+MLA = {"name": "tiny-mla", "num_layers": 2, "d_model": 64,
+       "vocab_size": 256, "block_pattern": ["mla"]}
+
+
+def test_a_configuration_counts_its_own_layers():
+    model = types.SimpleNamespace(
+        sequence_pass_flops=lambda a, s: 1000.0 * a["num_layers"] * s)
+    t = traffic(16)
+    # a difference round: 2 passes x 8 sequences; a full round: 2 anchor
+    # batches x 8 sequences
+    assert flops.round_flops(MLA, t, False, model) == 16 * 32_000.0
+    assert flops.round_flops(MLA, t, True, model) == 16 * 32_000.0
+
+
+@pytest.mark.parametrize("model", [None, types.SimpleNamespace()])
+def test_an_unknown_layer_kind_is_refused(model):
+    with pytest.raises(harness.BenchError,
+                       match="'tiny-mla'.*'mla'.*sequence_pass_flops"):
+        flops.round_flops(MLA, traffic(16), False, model)

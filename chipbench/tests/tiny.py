@@ -15,8 +15,25 @@ SMALL = {
 }
 
 
+# the uncompressed mamba2 mix, not yet a cell of BENCHMARK.json: the RandK
+# cell's configuration under its traffic file
+UNCOMPRESSED = "mamba2-130m.vrmarina-uncompressed"
+
+
+def load(workload):
+    """``harness.load_cell``, and the uncompressed mamba2 mix."""
+    if workload != UNCOMPRESSED:
+        return harness.load_cell(workload)
+    cell = harness.load_cell("mamba2-130m.vrmarina-randk")
+    with open(os.path.join(harness.BENCH_DIR, "traffic",
+                           "vrmarina-identity-s128x1.json")) as f:
+        cell.traffic = json.load(f)
+    cell.name = UNCOMPRESSED
+    return cell
+
+
 def tiny_cell(workload="mamba2-130m.vrmarina-randk", seq_len=32):
-    cell = harness.load_cell(workload)
+    cell = load(workload)
     arch = dict(cell.arch, **SMALL[cell.arch["block_pattern"][0]])
     arch["name"] = arch["name"] + "-tiny"
     cell.arch = arch
