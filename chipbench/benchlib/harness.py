@@ -231,8 +231,17 @@ def run_spec(cell: Cell, spec_seed: int):
 
 
 def build_program(cell: Cell, spec_seed: int):
+    """The program of ``run_spec``. The ``all_to_all`` backend builds its
+    mesh over every device JAX sees, so it runs only where those are the
+    cell's chips."""
+    import jax
     from repro.api import build
-    return build(run_spec(cell, spec_seed))
+    spec = run_spec(cell, spec_seed)
+    seen = len(jax.devices())
+    if spec.agg_mode == "all_to_all" and seen != cell.chips:
+        raise BenchError(f"agg_mode 'all_to_all' meshes every device JAX "
+                         f"sees: {seen}, for a cell of {cell.chips} chip(s)")
+    return build(spec)
 
 
 def same_layout(a, b) -> bool:
@@ -349,9 +358,11 @@ class Schedule:
 
 def plant_fault(exp, traffic, fault: str):
     """Break the timed path underneath the harness (the benchmark's own
-    tests): ``unchanged_state`` (the step returns its state as it got it)
-    or ``half_batch`` (half of every sequence's labels left out, the loss
-    the mean over the rest)."""
+    tests): ``unchanged_state`` (the step returns its state as it got it),
+    ``half_batch`` (half of every sequence's labels left out, the loss
+    the mean over the rest) or ``no_exchange`` (the ``all_to_all``
+    aggregation without its collectives: each chip keeps its own worker's
+    message as the aggregate)."""
     import jax
     if fault == "unchanged_state":
         step = exp.method.step
@@ -366,8 +377,43 @@ def plant_fault(exp, traffic, fault: str):
         half = jax.jit(halve_labels)
         exp.minibatch = lambda it, key=None: half(mb(it))
         exp.anchor = lambda it: half(an(it))
+    elif fault == "no_exchange":
+        from repro.core import sharded_agg
+        exp.method = dataclasses.replace(
+            exp.method, init=_swapped(exp.method.init, sharded_agg),
+            step=_swapped(exp.method.step, sharded_agg))
+        exp.__dict__.pop("step", None)
     else:
         raise ValueError(f"unknown fault {fault!r}")
+
+
+def _swapped(fn, sharded_agg):
+    """``fn`` traced with ``tree_aggregate_all_to_all`` replaced by the same
+    ``shard_map`` without its all-to-all and all-gather."""
+    import jax
+    from jax.sharding import PartitionSpec as P
+
+    def local(cfg, key, sent):
+        w = tuple(cfg.worker_axes)
+        w = w if len(w) > 1 else w[0]
+
+        def one(leaf, spec):
+            spec = tuple(spec) if spec is not None else ()
+            return jax.shard_map(lambda x: x[0], mesh=cfg.mesh,
+                                 in_specs=P(w, *spec), out_specs=P(*spec),
+                                 check_vma=False)(leaf)
+
+        return jax.tree.map(one, sent, cfg.grad_specs)
+
+    def swapped(*args):
+        real = sharded_agg.tree_aggregate_all_to_all
+        sharded_agg.tree_aggregate_all_to_all = local
+        try:
+            return fn(*args)
+        finally:
+            sharded_agg.tree_aggregate_all_to_all = real
+
+    return swapped
 
 
 def halve_labels(batch, axis=2):
@@ -400,9 +446,13 @@ def check_rounds(exp, schedule_of):
         t = time.perf_counter()
         args = exp.step_args(state, it, k_run)
         if step_args is None:
+            # an uncommitted argument's sharding is the call's choice, not
+            # its own: given to ``lower`` it would make another program,
+            # which a traced run then compiles again
             step_args = jax.tree.map(
-                lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
-                                               sharding=a.sharding), args)
+                lambda a: jax.ShapeDtypeStruct(
+                    a.shape, a.dtype,
+                    sharding=a.sharding if a.committed else None), args)
         state, m = exp.step(*args)
         jax.block_until_ready(state)
         t_rounds.append(time.perf_counter() - t)
@@ -443,8 +493,9 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool, *,
     t = time.perf_counter()
     devs = check_devices(cell.chips, require_tpu)
     dev = devs[0]
-    log(f"device {dev.platform} {dev.device_kind!r} x{len(devs)}; backend "
-        f"start {time.perf_counter() - t:.3f} s")
+    log(f"device {dev.platform} {dev.device_kind!r} x{len(devs)} of the "
+        f"{len(jax.devices())} JAX sees; backend start "
+        f"{time.perf_counter() - t:.3f} s")
     peaks = peaks_for(dev.device_kind) if require_tpu else None
     clog = CompileLog()
     log(f"compile cache {enable_cache()}")
@@ -502,6 +553,7 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool, *,
               "count": len(devs), "memory_peak_bytes": peak}
     if trace:
         from benchlib import layers_report
+        from benchlib.trace import plane_name
         compiled = exp.step.lower(*step_args).compile()
         mem = compiled.memory_analysis()
         if mem is not None:
@@ -511,8 +563,9 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool, *,
                 f"{mem.temp_size_in_bytes}, aliased "
                 f"{mem.alias_size_in_bytes} B")
         hlo = compiled.as_text()
-        rep = layers_report.reduce(tdir, hlo, cell, rounds, ck, window_s,
-                                   peaks)
+        rep = layers_report.reduce(
+            tdir, hlo, cell, rounds, ck, window_s, peaks,
+            [plane_name(d) for d in devs])
         per_layer, breakdown = rep["metrics"], rep["breakdown"]
         device["busy_s"] = rep["busy_s"]
         device["window_s"] = rep["window_s"]

@@ -24,7 +24,9 @@ class Context:
     trace_window_s: float   # first to last device op of the step program
     window_flops: float     # model FLOPs the window's rounds need
     agg_bytes: float        # least HBM bytes of one round's aggregation
-    peaks: dict
+    peaks: dict             # of one chip
+    chips: int              # the cell's chips, which share the work
+    planes: list            # their planes in the trace
 
     def layer_ms_per_round(self, layer):
         ns = self.layer_ns.get(layer)
@@ -33,9 +35,11 @@ class Context:
         return ns / 1e6 / self.rounds
 
 
-def reduce(tdir, hlo_text, cell, rounds, coins, window_s, peaks):
+def reduce(tdir, hlo_text, cell, rounds, coins, window_s, peaks, planes):
+    """``planes``: the trace's planes of the devices the cell runs on
+    (``trace.plane_name``); the times are means over them."""
     pd = trace.load(tdir)
-    dev = trace.device_events(pd)
+    dev = trace.device_events(pd, planes)
     if not dev:
         raise harness.BenchError("the trace holds no device op events")
     layers = trace.load_layers(os.path.join(harness.BENCH_DIR, "layers"))
@@ -62,7 +66,7 @@ def reduce(tdir, hlo_text, cell, rounds, coins, window_s, peaks):
                   window_flops=window_flops,
                   agg_bytes=flops.aggregation_bytes(
                       t["n_workers"], n_params, cell.arch["dtype"]),
-                  peaks=peaks)
+                  peaks=peaks, chips=cell.chips, planes=list(planes))
     total = sum(layer_ns.values()) or 1.0
     harness.log("device time by layer: " + ", ".join(
         f"{k} {v / 1e6:.3f} ms ({100 * v / total:.1f}%)"

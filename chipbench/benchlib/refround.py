@@ -81,9 +81,9 @@ def randk(key, x, ratio):
     return jnp.where(keep, x * (n_units / k_units), 0.0)
 
 
-def alie_bucketed_median(cand, perm, n_byz, z, bucket):
-    """cand (n, ...): ALIE on the first ``n_byz`` rows, then the coordinate
-    median of the bucket means in ``perm``'s order."""
+def alie(cand, n_byz, z):
+    """cand (n, ...): the first ``n_byz`` rows replaced by mean - z std of
+    the others, coordinate by coordinate."""
     n = cand.shape[0]
     shape = (n,) + (1,) * (cand.ndim - 1)
     good = (jnp.arange(n) >= n_byz).reshape(shape)
@@ -91,7 +91,21 @@ def alie_bucketed_median(cand, perm, n_byz, z, bucket):
     cnt = jnp.sum(w)
     mean = jnp.sum(cand * w, 0) / cnt
     std = jnp.sqrt(jnp.sum(jnp.square(cand - mean) * w, 0) / cnt)
-    sent = jnp.where(good, cand, (mean - z * std)[None])
+    return jnp.where(good, cand, (mean - z * std)[None])
+
+
+def alie_first_row(cand, perm, n_byz, z, bucket):
+    """The planted fault ``no_exchange``: no aggregate, the first worker's
+    message after the attack (what the first chip holds of the sharded
+    aggregation without its exchange)."""
+    return alie(cand, n_byz, z)[0]
+
+
+def alie_bucketed_median(cand, perm, n_byz, z, bucket):
+    """cand (n, ...): ALIE on the first ``n_byz`` rows, then the coordinate
+    median of the bucket means in ``perm``'s order."""
+    n = cand.shape[0]
+    sent = alie(cand, n_byz, z)
     if bucket > 1:
         xp = sent[perm]
         nb = -(-n // bucket)
@@ -128,10 +142,11 @@ class Reference:
     tensors rounded as ``benchlib.refops.make_policy`` says (``"fp8"``, the
     control; ``"bf16"``, the witness). ``batch_fault`` optionally rewrites
     a batch before the loss (a planted fault: half of each sequence left
-    out)."""
+    out); ``agg_fault`` ``"no_exchange"`` plants ``alie_first_row`` in the
+    aggregation's place."""
 
     def __init__(self, model, arch: dict, traffic: dict, precision="f32",
-                 batch_fault=None):
+                 batch_fault=None, agg_fault=None):
         check_modelled(traffic)
         self.arch, self.t = arch, traffic
         self.store = jnp.dtype(arch["dtype"])
@@ -151,8 +166,11 @@ class Reference:
 
         self._vg_diff = jax.jit(vg_diff)
         t = traffic
+        if agg_fault not in (None, "no_exchange"):
+            raise ValueError(f"unknown aggregation fault {agg_fault!r}")
         self._agg = jax.jit(functools.partial(
-            alie_bucketed_median, n_byz=t["n_byz"],
+            alie_first_row if agg_fault else alie_bucketed_median,
+            n_byz=t["n_byz"],
             z=t["attack_kwargs"]["z"], bucket=t["bucket_size"]))
         ratio = t["compressor_kwargs"].get("ratio")
         self._q = (jax.jit(functools.partial(randk, ratio=ratio))

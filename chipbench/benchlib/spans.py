@@ -48,6 +48,10 @@ ROUND_KINDS = ("full_round", "diff_round")
 SPANS = ("feed", "dispatch", "log", "checkpoint")
 ROUND_SPAN = "round"
 UNSCOPED = "unscoped"
+# the HLO opcodes that move data between chips; the asynchronous ones also
+# as their -start and -done halves
+COLLECTIVES = ("all-to-all", "all-gather", "all-reduce", "reduce-scatter",
+               "collective-permute")
 
 _WRAP = re.compile(r"^(\w+)\((.*)\)$")
 
@@ -144,6 +148,26 @@ def hlo_scopes(hlo_text: str) -> dict:
             for name, op in hlo_op_names(hlo_text).items()}
 
 
+_OPCODE = re.compile(r"\s([a-z][\w\-]*)\(")
+
+
+def hlo_collectives(hlo_text: str) -> set:
+    """The instructions of an HLO text whose opcode is a collective
+    (``COLLECTIVES``). The opcode is the first word after the result's type
+    that opens an operand list: a type holds no space before a parenthesis
+    (``bf16[8]{0:T(8,128)}``, ``(f32[8]{0}, s32[])``)."""
+    out = set()
+    for line in hlo_text.splitlines():
+        m = trace._INSTR.match(line)
+        if not m:
+            continue
+        op = _OPCODE.search(" " + m.group(2))
+        if op and op.group(1).removesuffix("-start").removesuffix(
+                "-done") in COLLECTIVES:
+            out.add(m.group(1))
+    return out
+
+
 def has_scopes(scopes: dict, names) -> bool:
     return any(layer in names or kind in names
                for layer, kind in scopes.values())
@@ -187,19 +211,21 @@ class DeviceScopes:
     kind_ns: dict           # round kind -> self time of ops under it
     kind_execs: dict        # round kind -> step executions holding its ops
     n_execs: int
+    exchange_ns: float = 0.0  # self time of the collectives (hlo_collectives)
 
 
-def device_scopes(dev: dict, execs: dict, module: str,
-                  scopes: dict) -> DeviceScopes:
+def device_scopes(dev: dict, execs: dict, module: str, scopes: dict,
+                  collectives=frozenset()) -> DeviceScopes:
     """``dev``: {plane: [(start, end, module, op)]} (``trace.device_events``);
     ``execs``: {plane: [(start, end, execution name)]}; ``module``: the step
-    program's name (``jit_step``); ``scopes``: ``hlo_scopes`` of its HLO."""
+    program's name (``jit_step``); ``scopes``: ``hlo_scopes`` of its HLO;
+    ``collectives``: ``hlo_collectives`` of its HLO."""
     per_plane = []
     for plane, evs in dev.items():
         mine = sorted(ex for ex in execs.get(plane, ())
                       if trace._module_name(ex[2]) == module)
         starts = [x[0] for x in mine]
-        layer_ns, kind_ns = {}, {}
+        layer_ns, kind_ns, exchange = {}, {}, 0.0
         kinds = [set() for _ in mine]
         step = sorted((e for e in evs if trace._module_name(e[2]) == module),
                       key=lambda e: (e[0], -e[1]))
@@ -207,6 +233,8 @@ def device_scopes(dev: dict, execs: dict, module: str,
             layer, kind = scopes.get(op, (None, None))
             key = layer or UNSCOPED
             layer_ns[key] = layer_ns.get(key, 0.0) + ns
+            if op in collectives:
+                exchange += ns
             if kind:
                 kind_ns[kind] = kind_ns.get(kind, 0.0) + ns
                 i = bisect.bisect_right(starts, s) - 1
@@ -218,7 +246,8 @@ def device_scopes(dev: dict, execs: dict, module: str,
         per_plane.append((layer_ns, kind_ns,
                           {k: sum(k in ks for ks in kinds)
                            for k in ROUND_KINDS},
-                          sum(e - s for s, e, _ in mine), len(mine)))
+                          sum(e - s for s, e, _ in mine), len(mine),
+                          exchange))
     n = max(len(per_plane), 1)
     counts = {(pp[2]["full_round"], pp[2]["diff_round"], pp[4])
               for pp in per_plane}
@@ -238,7 +267,8 @@ def device_scopes(dev: dict, execs: dict, module: str,
         layer_ns=layer_ns, step_ns=sum(layer_ns.values()),
         exec_ns=sum(pp[3] for pp in per_plane) / n, kind_ns=mean(1),
         kind_execs=dict(per_plane[0][2]) if per_plane else {},
-        n_execs=per_plane[0][4] if per_plane else 0)
+        n_execs=per_plane[0][4] if per_plane else 0,
+        exchange_ns=sum(pp[5] for pp in per_plane) / n)
 
 
 # --------------------------------------------------------------------------
@@ -407,10 +437,11 @@ class Spans:
         return ns / 1e6 / self.rounds
 
 
-def reduce(pd, xplane_path: str, rounds: int, log=print):
+def reduce(pd, xplane_path: str, rounds: int, log=print, planes=None):
     """-> ``Spans`` of a loaded trace, or None where the step program
-    carries no scopes and the loop no spans."""
-    dev = trace.device_events(pd)
+    carries no scopes and the loop no spans. ``planes``: the cell's device
+    planes (``trace.device_events``)."""
+    dev = trace.device_events(pd, planes)
     if not dev:
         return None
     execs = {}
@@ -420,13 +451,7 @@ def reduce(pd, xplane_path: str, rounds: int, log=print):
                 execs[plane.name] = [
                     (float(ev.start_ns), float(ev.start_ns + ev.duration_ns),
                      ev.name) for ev in line.events]
-    # the step program: the one that holds the most device time
-    busy = {}
-    for evs in dev.values():
-        for mod, _, ns in trace.self_times(evs):
-            key = trace._module_name(mod)
-            busy[key] = busy.get(key, 0.0) + ns
-    module = max(busy, key=busy.get)
+    module = trace.step_module(dev)
     names = {ex[2] for exs in execs.values() for ex in exs
              if trace._module_name(ex[2]) == module}
     protos = {k: v for k, v in recorded_hlo(xplane_path).items()
@@ -436,7 +461,8 @@ def reduce(pd, xplane_path: str, rounds: int, log=print):
     if proto is None:
         log(f"the trace records no HLO of {module} ({len(protos)} of that "
             "name): no scopes to read")
-    scopes = hlo_scopes(hlo_text(proto)) if proto is not None else {}
+    text = hlo_text(proto) if proto is not None else ""
+    scopes = hlo_scopes(text)
     has_layers = has_scopes(scopes, LAYERS)
     has_kinds = has_scopes(scopes, ROUND_KINDS)
 
@@ -447,20 +473,23 @@ def reduce(pd, xplane_path: str, rounds: int, log=print):
     if n_round_spans and n_round_spans != rounds:
         raise BenchError(f"the trace holds {n_round_spans} round spans in "
                          f"a window of {rounds} rounds")
-    device = device_scopes(dev, execs, module, scopes)
+    device = device_scopes(dev, execs, module, scopes,
+                           hlo_collectives(text))
     host_ns = {k: sum(e - s for s, e in host[k]) for k in SPANS}
     log_idle = sum(idle_within(evs, host["log"])
                    for evs in dev.values()) / len(dev)
     out = Spans(rounds=rounds, device=device, has_layers=has_layers,
                 has_kinds=has_kinds, host_ns=host_ns,
                 n_round_spans=n_round_spans, log_idle_ns=log_idle)
-    _log(out, module, log)
+    _log(out, module, sorted(dev), log)
     return out
 
 
-def _log(sp: Spans, module: str, log) -> None:
+def _log(sp: Spans, module: str, planes: list, log) -> None:
     d, r = sp.device, sp.rounds
     total = d.step_ns or 1.0
+    log(f"device planes read: {planes}; collectives "
+        f"{d.exchange_ns / 1e6 / r:.3f} ms per round")
     log(f"device time by scope ({module}, per round): " + ", ".join(
         f"{k} {v / 1e6 / r:.3f} ms ({100 * v / total:.2f}%)"
         for k, v in sorted(d.layer_ns.items(), key=lambda kv: -kv[1])))
@@ -494,9 +523,10 @@ def _read_trace(ctx, tdir):
     if not paths:
         return None
     pd = trace.load(tdir)
-    dev = trace.device_events(pd)
+    dev = trace.device_events(pd, ctx.planes)
     busy = (sum(trace.union_ns(evs) for evs in dev.values())
             / max(len(dev), 1) / 1e9)
     if not dev or abs(busy - ctx.busy_s) > 1e-9 * max(busy, 1.0):
         return None
-    return reduce(pd, paths[-1], ctx.rounds, log=harness.log)
+    return reduce(pd, paths[-1], ctx.rounds, log=harness.log,
+                  planes=ctx.planes)

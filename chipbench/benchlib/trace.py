@@ -69,12 +69,22 @@ def _module_name(name: str) -> str:
     return name.split("(", 1)[0]
 
 
-def device_events(pd, platform_prefix="/device:TPU:"):
+def plane_name(device) -> str:
+    """The trace's plane of a JAX device."""
+    return f"/device:TPU:{device.id}"
+
+
+def device_events(pd, planes=None, platform_prefix="/device:TPU:"):
     """-> {plane name: [(start_ns, end_ns, module, op)]} of op events.
 
     A device plane holds a module line ("XLA Modules": one event per
     program execution, ``jit_step(<id>)``) and an op line ("XLA Ops"); an
-    op belongs to the module execution whose interval holds its start."""
+    op belongs to the module execution whose interval holds its start.
+
+    ``planes``: the planes of the devices the cell runs on (``plane_name``).
+    Only those are kept; another plane that holds an op of the step program
+    (``step_module`` of the kept planes) fails the run, since the cell's
+    work would then run on a device it does not count."""
     out = {}
     for plane in pd.planes:
         if not plane.name.startswith(platform_prefix):
@@ -102,7 +112,31 @@ def device_events(pd, platform_prefix="/device:TPU:"):
             evs.append((s, e, mod, op))
         if evs:
             out[plane.name] = evs
-    return out
+    if planes is None:
+        return out
+    from benchlib.harness import BenchError
+    kept = {p: evs for p, evs in out.items() if p in planes}
+    if out and not kept:
+        raise BenchError(f"the trace holds none of the cell's device planes "
+                         f"{sorted(planes)}, only {sorted(out)}")
+    step = step_module(kept)
+    stray = sorted(p for p, evs in out.items() if p not in kept and any(
+        _module_name(mod) == step for _, _, mod, _ in evs))
+    if stray:
+        raise BenchError(f"ops of {step} on device planes {stray}, outside "
+                         f"the cell's {sorted(planes)}")
+    return kept
+
+
+def step_module(dev) -> str:
+    """The step program of ``device_events``' planes: the module that holds
+    the most device self time."""
+    busy = {}
+    for evs in dev.values():
+        for mod, _, ns in self_times(evs):
+            key = _module_name(mod)
+            busy[key] = busy.get(key, 0.0) + ns
+    return max(busy, key=busy.get) if busy else None
 
 
 def host_events(pd):

@@ -2,7 +2,8 @@
 """Readings that set a cell's correctness limits, read in one process.
 
     python3 chipbench/calibrate.py --workload <name> --seeds 1,2,... \
-        --control-seeds 1,2,3 --fault-seeds 1,2,3 [--witness-seeds 1,2]
+        --control-seeds 1,2,3 --fault-seeds 1,2,3 \
+        [--exchange-fault-seeds 1,2,3] [--witness-seeds 1,2]
 
 For each seed of ``--seeds``: the program's first rounds (the same compiled
 step and feed as a run, re-seeded), then the float32 reference; the gaps
@@ -10,7 +11,10 @@ between them are the program's readings (the lower end of each limit).
 For each seed of ``--control-seeds``: the control (the reference with its
 weight products in float8) against the float32 reference. For each of
 ``--fault-seeds``: the reference with half of every sequence's labels
-left out against the whole. For each of ``--witness-seeds``: the
+left out against the whole. For each of ``--exchange-fault-seeds``: the
+reference with the first worker's message in the aggregate's place (what
+the first chip holds of the ``all_to_all`` aggregation without its
+exchange) against the whole. For each of ``--witness-seeds``: the
 reference computed in the configuration's own bfloat16 rounding, against
 the float32 reference and against the program (a look at where a gap
 comes from). One JSON line per reading on stdout, with the leaf norms
@@ -32,8 +36,8 @@ HERE = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(HERE)
 
 
-def calibrate(cell, seeds, control_seeds, fault_seeds, witness_seeds=(), *,
-              require_tpu=True, emit=print):
+def calibrate(cell, seeds, control_seeds, fault_seeds, witness_seeds=(),
+              exchange_fault_seeds=(), *, require_tpu=True, emit=print):
     from benchlib import check, harness
     from benchlib.refround import Reference
 
@@ -53,9 +57,12 @@ def calibrate(cell, seeds, control_seeds, fault_seeds, witness_seeds=(), *,
         refs["half_batch"] = Reference(
             cell.model, cell.arch, cell.traffic,
             batch_fault=functools.partial(harness.halve_labels, axis=1))
+    if exchange_fault_seeds:
+        refs["no_exchange"] = Reference(cell.model, cell.arch, cell.traffic,
+                                        agg_fault="no_exchange")
     readings = []
     for seed in sorted(set(seeds) | set(control_seeds) | set(fault_seeds)
-                       | set(witness_seeds)):
+                       | set(witness_seeds) | set(exchange_fault_seeds)):
         traffic, k_init = harness.reseed(exp, cell, seed)
         _, _, k_run, _ = harness.seed_keys(seed)
         runs = []
@@ -71,6 +78,7 @@ def calibrate(cell, seeds, control_seeds, fault_seeds, witness_seeds=(), *,
         harness.log(f"seed {seed}: reference {time.perf_counter() - t:.1f} s")
         for kind, seed_set in (("control", control_seeds),
                                ("half_batch", fault_seeds),
+                               ("no_exchange", exchange_fault_seeds),
                                ("witness", witness_seeds)):
             if seed in seed_set:
                 runs.append((kind, refs[kind].run(params0, traffic, k_run,
@@ -97,7 +105,7 @@ def calibrate(cell, seeds, control_seeds, fault_seeds, witness_seeds=(), *,
     for name in check.NUMBERS:
         prog = [r["numbers"][name] for r in readings if r["kind"] == "program"]
         summary[name] = {"lower": max(prog) if prog else None}
-        for kind in ("control", "half_batch"):
+        for kind in ("control", "half_batch", "no_exchange"):
             vals = [r["numbers"][name] for r in readings if r["kind"] == kind]
             summary[name][kind] = min(vals) if vals else None
     emit(json.dumps({"summary": summary}))
@@ -110,6 +118,7 @@ def main() -> int:
     ap.add_argument("--seeds", required=True)
     ap.add_argument("--control-seeds", default="")
     ap.add_argument("--fault-seeds", default="")
+    ap.add_argument("--exchange-fault-seeds", default="")
     ap.add_argument("--witness-seeds", default="")
     args = ap.parse_args()
     sys.path[:0] = [HERE, os.path.join(ROOT, "src")]
@@ -121,7 +130,7 @@ def main() -> int:
     try:
         calibrate(harness.load_cell(args.workload), seeds(args.seeds),
                   seeds(args.control_seeds), seeds(args.fault_seeds),
-                  seeds(args.witness_seeds),
+                  seeds(args.witness_seeds), seeds(args.exchange_fault_seeds),
                   emit=lambda line: print(line, flush=True))
     except harness.BenchError as e:
         print(f"[bench] error: {e}", file=sys.stderr, flush=True)

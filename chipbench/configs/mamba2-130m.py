@@ -24,6 +24,13 @@ import jax.numpy as jnp
 
 from benchlib.refops import next_token_xent, normal, rms_norm
 
+# the quadratic form keeps (b, t, t, heads) float32 tensors of every layer
+# for the backward pass; past this many bytes of them a layer is computed
+# again in the backward pass (``jax.checkpoint``: the same arithmetic, to
+# float32 rounding), so that long sequences fit on the chip. 2,048 tokens
+# would keep 19 GB; 128 keep 75 MB and are computed as before
+SAVED_BYTES = 1 << 30
+
 
 def _sizes(a):
     d = a["d_model"]
@@ -117,6 +124,8 @@ def loss(params, batch, a, policy):
         y = rd(rms_norm(y, m["out_norm"], eps))
         return rd(x + mm("bte,ed->btd", y, m["w_out"])), None
 
+    if a["num_layers"] * b * t * t * nh * 4 > SAVED_BYTES:
+        layer = jax.checkpoint(layer)
     x, _ = jax.lax.scan(layer, x, params["groups"][0])
     x = rd(rms_norm(x, params["final_norm"], eps))
     return next_token_xent(mm("bsd,vd->bsv", x, emb), batch["labels"])

@@ -50,3 +50,13 @@ def test_verdict():
                               "dx_gap": 0.4}, limits)[0]
     assert not check.verdict({"loss_gap": 0.6, "g0_gap": 0.2,
                               "dx_gap": 0.4}, limits)[0]
+
+
+def test_a_number_without_a_limit_is_printed_and_not_compared():
+    limits = {k: {"limit": 0.5} for k in check.NUMBERS}
+    limits["loss_gap"] = {"limit": None}
+    ok, checks = check.verdict({"loss_gap": 7.0, "g0_gap": 0.2,
+                                "dx_gap": 0.4}, limits)
+    assert ok and checks["loss_gap"] == {"value": 7.0, "limit": None}
+    assert not check.verdict({"loss_gap": 7.0, "g0_gap": 0.6,
+                              "dx_gap": 0.4}, limits)[0]

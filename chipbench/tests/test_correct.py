@@ -35,7 +35,10 @@ def test_planted_fault_is_not_correct(fault, workload):
     assert not out["correct"], out["checks"]
 
 
-@pytest.mark.parametrize("workload", WORKLOADS)
+# the control needs no program, so the four-chip mix's tiny cell is here
+# too (its runs are in test_chips.py, on four virtual devices)
+@pytest.mark.parametrize("workload",
+                         WORKLOADS + ["mamba2-130m.vrmarina-a2a-4chip"])
 def test_control_is_not_correct(workload):
     cell = tiny_cell(workload)
     init = jax.jit(lambda k: cell.model.init_params(k, cell.arch))

@@ -77,6 +77,9 @@ def test_mamba2_130m_parameter_count():
     ("mamba2-130m.vrmarina-randk", 1_581_596_540_928),
     ("musicgen-medium.vrmarina-uncompressed", 20_979_327_172_608),
     ("mamba2-130m.vrmarina-uncompressed", 1_581_596_540_928),
+    # 4 workers x 2 passes (or 2 anchor sequences) x 2,048 tokens: 8 x
+    # 6 x 2,048 x 128,710,656
+    ("mamba2-130m.vrmarina-a2a-4chip", 12_652_772_327_424),
 ])
 def test_round_flops_of_each_cell(workload, want, full):
     # what step_mfu reads: the cell's own traffic and module

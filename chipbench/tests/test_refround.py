@@ -51,6 +51,32 @@ def test_rounds_give_the_recorded_aggregates(workload, full, diff):
     assert digest(loss, g1) == diff
 
 
+def test_recomputed_layers_give_the_same_gradients(monkeypatch):
+    # long sequences recompute mamba2's layers in the backward pass
+    # (configs/mamba2-130m.py, SAVED_BYTES): the loss and gradients are
+    # those of the stored layers, to float32 rounding
+    from benchlib.refops import make_policy
+    cell = tiny_cell("mamba2-130m.vrmarina-a2a-4chip")
+    _, k_init, _, k_data = harness.seed_keys(SEED)
+    batch = jax.tree.map(lambda a: a[0], Traffic(
+        cell.arch, cell.traffic, k_data).anchor(0))
+    x0 = jax.tree.map(lambda a: a.astype(jnp.float32),
+                      cell.model.init_params(k_init, cell.arch))
+
+    def grads():
+        return jax.jit(jax.value_and_grad(lambda p: cell.model.loss(
+            p, batch, cell.arch, make_policy("f32"))))(x0)
+
+    stored = grads()
+    monkeypatch.setattr(cell.model, "SAVED_BYTES", 0)
+    recomputed = grads()
+    assert float(recomputed[0]) == float(stored[0])
+    for a, b in zip(jax.tree.leaves(recomputed[1]),
+                    jax.tree.leaves(stored[1])):
+        np.testing.assert_allclose(a, b, rtol=0, atol=1e-5 * float(
+            jnp.max(jnp.abs(b))))
+
+
 @pytest.mark.parametrize("change,match", [
     ({"aggregator": "rfa"}, "aggregator 'rfa'"),
     ({"method": "sgd"}, "method 'sgd'"),

@@ -6,6 +6,7 @@ from repro.api import RunSpec, resolve_agg_mode
 from benchlib import harness
 from tiny import load
 
+
 SHARED = dict(task="lm", method="marina", n_workers=8, n_byz=1,
               attack="ALIE", attack_kwargs={"z": 1.06}, aggregator="cm",
               bucket_size=2, p=0.1, lr=1e-4, steps=3, seed=7)
@@ -28,6 +29,11 @@ SHARED = dict(task="lm", method="marina", n_workers=8, n_byz=1,
         agg_mode=resolve_agg_mode("auto"),
         data_kwargs={"seq_len": 128, "per_worker_batch": 1,
                      "remat": False}, **SHARED)),
+    ("mamba2-130m.vrmarina-a2a-4chip", dict(
+        SHARED, n_workers=4, bucket_size=1, arch="mamba2-130m",
+        compressor="identity", compressor_kwargs={}, agg_mode="all_to_all",
+        data_kwargs={"seq_len": 2048, "per_worker_batch": 1,
+                     "remat": True})),
 ])
 def test_run_spec_of_each_cell(workload, want):
     got = harness.run_spec(load(workload), 7)

@@ -15,20 +15,22 @@ SMALL = {
 }
 
 
-# the uncompressed mamba2 mix, not yet a cell of BENCHMARK.json: the RandK
-# cell's configuration under its traffic file
-UNCOMPRESSED = "mamba2-130m.vrmarina-uncompressed"
+# the four-chip mix, not yet a cell of BENCHMARK.json: the RandK cell's
+# configuration under its traffic and limits files, on 4 chips
+A2A = "mamba2-130m.vrmarina-a2a-4chip"
 
 
 def load(workload):
-    """``harness.load_cell``, and the uncompressed mamba2 mix."""
-    if workload != UNCOMPRESSED:
+    """``harness.load_cell``, and the four-chip mamba2 mix."""
+    if workload != A2A:
         return harness.load_cell(workload)
     cell = harness.load_cell("mamba2-130m.vrmarina-randk")
-    with open(os.path.join(harness.BENCH_DIR, "traffic",
-                           "vrmarina-identity-s128x1.json")) as f:
-        cell.traffic = json.load(f)
-    cell.name = UNCOMPRESSED
+    for attr, sub, name in (("traffic", "traffic",
+                             "vrmarina-a2a-identity-s2048x1"),
+                            ("limits", "limits", A2A)):
+        with open(os.path.join(harness.BENCH_DIR, sub, name + ".json")) as f:
+            setattr(cell, attr, json.load(f))
+    cell.name, cell.chips = A2A, 4
     return cell
 
 
